@@ -1,12 +1,17 @@
 """ctypes driver for the C++ Dijkstra baseline (benchmarks/cpp/spf_baseline.cpp).
 
-Compiles on demand with g++ -O3 (cached by source mtime) — the baseline for
-`vs_baseline` is real native sequential Dijkstra, not a Python oracle."""
+Compiles on demand with g++ -O3 — the baseline for `vs_baseline` is real
+native sequential Dijkstra, not a Python oracle.  The built library is
+named by a hash of the source, the flags and this machine's CPU
+(-march=native), so a library copied from another checkout or machine
+is never loaded."""
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 from pathlib import Path
 
@@ -14,29 +19,37 @@ import numpy as np
 
 _DIR = Path(__file__).resolve().parent
 _SRC = _DIR / "cpp" / "spf_baseline.cpp"
-_SO = _DIR / "cpp" / "build" / "libspf_baseline.so"
+_BUILD = _DIR / "cpp" / "build"
+_FLAGS = ["-O3", "-march=native", "-std=c++17", "-shared", "-fPIC"]
 
 _lib = None
 
 
+def _cpu_identity() -> bytes:
+    try:
+        with open("/proc/cpuinfo", "rb") as f:
+            lines = [
+                ln for ln in f
+                if ln.startswith((b"model name", b"flags"))
+            ]
+        return b"".join(sorted(set(lines)))
+    except OSError:
+        return platform.processor().encode()
+
+
 def _ensure_built() -> Path:
-    _SO.parent.mkdir(parents=True, exist_ok=True)
-    if not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime:
+    key = hashlib.sha256(
+        _SRC.read_bytes() + " ".join(_FLAGS).encode() + _cpu_identity()
+    ).hexdigest()[:16]
+    so = _BUILD / f"libspf_baseline-{key}.so"
+    if not so.exists():
+        _BUILD.mkdir(parents=True, exist_ok=True)
+        tmp = so.with_suffix(f".{os.getpid()}.tmp")
         subprocess.run(
-            [
-                "g++",
-                "-O3",
-                "-march=native",
-                "-std=c++17",
-                "-shared",
-                "-fPIC",
-                str(_SRC),
-                "-o",
-                str(_SO),
-            ],
-            check=True,
+            ["g++", *_FLAGS, str(_SRC), "-o", str(tmp)], check=True
         )
-    return _SO
+        os.replace(tmp, so)
+    return so
 
 
 def load():

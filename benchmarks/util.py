@@ -3,35 +3,54 @@
 from __future__ import annotations
 
 import json
-import os
 import time
 from typing import Callable, Optional
 
-# Peak HBM bandwidth of the bench device, bytes/s.  Default is the
-# v5e figure (819 GB/s per chip); override with OPENR_PEAK_HBM_BW for
-# other parts so utilization fractions stay honest across hardware.
-PEAK_HBM_BW = float(os.environ.get("OPENR_PEAK_HBM_BW", 819e9))
+# Published peaks per chip, keyed by JAX's `device_kind`.  Source: Google
+# Cloud documentation, "TPU v5e" (system architecture): 16 GB HBM2 at
+# 819 GB/s, 197 TFLOP/s bf16.  A device that is not here is an error,
+# never a default.
+_V5E = {
+    "hbm_bytes_per_s": 819e9,
+    "bf16_flops_per_s": 197e12,
+    "source": 'Google Cloud "TPU v5e" documentation',
+}
+PEAKS = {"TPU v5 lite": _V5E, "TPU v5e": _V5E}
+
+
+def device_peaks(device_kind: Optional[str] = None) -> dict:
+    """Peak table entry for `device_kind` (default: JAX's first device);
+    raises KeyError for a device the table does not know."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device_kind {device_kind!r}; add it "
+            f"to benchmarks.util.PEAKS with its source"
+        ) from None
 
 
 def achieved_bw_frac(
-    bytes_moved: Optional[float], wall_ms: Optional[float]
+    bytes_moved: Optional[float],
+    wall_ms: Optional[float],
+    device_kind: Optional[str] = None,
 ) -> Optional[float]:
-    """Fraction of peak HBM bandwidth achieved: bytes-moved /
-    (wall x peak BW).  The utilization lens on every device row — a
-    memory-bound kernel near 1.0 is done; a small fraction says the
-    wall is dispatch/latency, not bandwidth.  None when either input is
-    missing/degenerate (e.g. a row that never timed)."""
+    """Fraction of the device's peak HBM bandwidth achieved: bytes-moved
+    / (wall x peak BW).  None when either input is missing/degenerate
+    (e.g. a row that never timed); an unknown device raises."""
     if not bytes_moved or not wall_ms or wall_ms <= 0:
         return None
-    return round(float(bytes_moved) / (wall_ms * 1e-3 * PEAK_HBM_BW), 4)
+    peak = device_peaks(device_kind)["hbm_bytes_per_s"]
+    return round(float(bytes_moved) / (wall_ms * 1e-3 * peak), 4)
 
 
-def peak_bw_source() -> str:
-    """Provenance of the PEAK_HBM_BW figure used by achieved_bw_frac:
-    "env" when the operator pinned OPENR_PEAK_HBM_BW, "default_v5e"
-    otherwise.  Recorded next to roofline fractions so a row compared
-    across machines says which denominator it was computed against."""
-    return "env" if os.environ.get("OPENR_PEAK_HBM_BW") else "default_v5e"
+def peak_bw_source(device_kind: Optional[str] = None) -> str:
+    """Provenance of the peak achieved_bw_frac divides by."""
+    return device_peaks(device_kind)["source"]
 
 
 def measure_ms(fn: Callable[[], None], reps: int = 3, warmup: int = 1) -> float:

@@ -787,12 +787,26 @@ def _run_drivers(roots, recorder: _Recorder) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _all_jaxprs(jaxpr) -> Iterator:
-    import jax.core as core
+def _params_jaxprs(value) -> Iterator:
+    """Jaxprs held in one eqn param value (a Jaxpr, a ClosedJaxpr, or a
+    tuple/list of them, as cond branches are)."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
+    if isinstance(value, Jaxpr):
+        yield value
+    elif isinstance(value, ClosedJaxpr):
+        yield value.jaxpr
+    elif isinstance(value, (tuple, list)):
+        for v in value:
+            yield from _params_jaxprs(v)
+
+
+def _all_jaxprs(jaxpr) -> Iterator:
     yield jaxpr
-    for sub in core.subjaxprs(jaxpr):
-        yield from _all_jaxprs(sub)
+    for eqn in jaxpr.eqns:
+        for value in eqn.params.values():
+            for sub in _params_jaxprs(value):
+                yield from _all_jaxprs(sub)
 
 
 def _count_eqns(jaxpr) -> int:

@@ -887,24 +887,14 @@ class DeviceResidencyEngine:
             )
 
     def run_pallas(self, kind: str, pallas_thunk, xla_thunk):
-        """Engine face of the Pallas demotion contract
+        """Engine face of the Pallas dispatch contract
         (ops.pallas_kernels.run_with_fallback): binds this engine's
-        counter and chaos seams so every launch, demotion and skip is
-        accounted under `device.engine.pallas_*`, and an armed
-        `engine:pallas` chaos fault demotes through the same path a
-        real Pallas failure takes."""
+        counter and chaos seams so every launch, skip and (interpret
+        mode only) demotion is accounted under `device.engine.pallas_*`."""
         from ..ops import pallas_kernels as pk
 
         tr = _trace.TRACE
-        if tr is None:
-            return pk.run_with_fallback(
-                kind,
-                pallas_thunk,
-                xla_thunk,
-                counters=self.counters,
-                fault_hook=self.fault_hook,
-                mode=self.pallas_mode,
-            )
+        skips0 = self.counters.get("device.engine.pallas_skips", 0)
         falls0 = self.counters.get("device.engine.pallas_fallbacks", 0)
         out = pk.run_with_fallback(
             kind,
@@ -914,16 +904,17 @@ class DeviceResidencyEngine:
             fault_hook=self.fault_hook,
             mode=self.pallas_mode,
         )
-        demoted = (
-            self.counters.get("device.engine.pallas_fallbacks", 0) > falls0
-        )
-        if self.pallas_mode == "off":
-            kernel = "xla"
-        elif demoted:
-            kernel = "fallback"
-        else:
-            kernel = "pallas"
-        tr.annotate("engine.kernel", f"{kind}:{kernel}")
+        if tr is not None:
+            if self.counters.get("device.engine.pallas_skips", 0) > skips0:
+                kernel = "xla"
+            elif (
+                self.counters.get("device.engine.pallas_fallbacks", 0)
+                > falls0
+            ):
+                kernel = "fallback"
+            else:
+                kernel = "pallas"
+            tr.annotate("engine.kernel", f"{kind}:{kernel}")
         return out
 
     # -- delta rung ----------------------------------------------------------

@@ -929,6 +929,9 @@ def main(argv: Optional[list[str]] = None) -> int:
     config = load_config(args.config)
     apply_flag_overrides(config, args)
     config.validate()
+    from .utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     daemon = OpenrDaemon(config, use_device_spf=args.use_device_spf)
     daemon.start()
     log.info(

@@ -49,6 +49,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 import numpy as np
 
 from .sssp import INF16, INF32, clamp_metric_u16, u16_saturation_verdict
@@ -371,60 +372,61 @@ def ecmp_bitmap_from_reverse_dist(
     u16 = drev.dtype == jnp.uint16
     inf = INF16 if u16 else INF32
 
-    def slot_on(k):
-        """[N, P] bool: out-slot k of every router is an ECMP hop."""
-        eidk = out.eid[:, k]
+    def slot_bits(k):
+        """(on [N, P] bool, slot [N]): out-slot k of every router is an
+        ECMP hop toward each destination."""
+        eidk = lax.dynamic_index_in_dim(out.eid, k, axis=1, keepdims=False)
         ok = (eidk >= 0) & jnp.take(edge_up, jnp.maximum(eidk, 0))
         w = jnp.take(edge_metric, jnp.maximum(eidk, 0))  # [N]
         if u16:
             w = clamp_metric_u16(w)
-        nbr = out.nbr[:, k]
+        nbr = lax.dynamic_index_in_dim(out.nbr, k, axis=1, keepdims=False)
         d_nbr = jnp.take(drev, nbr, axis=0)  # [N, P]
         nbr_ov = jnp.take(node_overloaded, nbr)  # [N]
-        return (
+        on = (
             ok[:, None]
             & (d_nbr < inf)
             & (d_nbr + w[:, None] == d_self)
             & (~nbr_ov[:, None] | (d_nbr == 0))
         )
-
-    if n_words == 1:
-        # single-word fast path (any topology with <=32 unique
-        # out-neighbors per node): a flat uint32 OR chain, no [N, P, W]
-        # broadcast scaffolding per slot
-        bitmap2d = jnp.zeros((n, p_dim), dtype=jnp.uint32)
-        for k in range(k_pad):
-            slot = out.slot[:, k]
-            bit = jnp.where(
-                slot >= 0,
-                jnp.uint32(1)
-                << (jnp.maximum(slot, 0) % 32).astype(jnp.uint32),
-                jnp.uint32(0),
-            )  # [N]
-            bitmap2d = bitmap2d | jnp.where(
-                slot_on(k), bit[:, None], jnp.uint32(0)
-            )
-        return bitmap2d[:, :, None]
-
-    bitmap = jnp.zeros((n, p_dim, n_words), dtype=jnp.uint32)
-    for k in range(k_pad):
-        on = slot_on(k)
-        slot = out.slot[:, k]
+        slot = lax.dynamic_index_in_dim(out.slot, k, axis=1, keepdims=False)
         bit = jnp.where(
             slot >= 0,
             jnp.uint32(1) << (jnp.maximum(slot, 0) % 32).astype(jnp.uint32),
             jnp.uint32(0),
         )  # [N]
+        return on, slot, bit
+
+    # a loop over the out-slots, not a static unroll: unrolled, XLA keeps
+    # every slot's [N, P] gather live at once (12.9 GB of temporaries and
+    # a two-minute v5e compile at fat-tree 10k, 124 slots x P = 10,080)
+    if n_words == 1:
+        # single-word fast path (any topology with <=32 unique
+        # out-neighbors per node): a flat uint32 OR chain, no [N, P, W]
+        # broadcast scaffolding per slot
+        def body1(k, bitmap2d):
+            on, _, bit = slot_bits(k)
+            return bitmap2d | jnp.where(on, bit[:, None], jnp.uint32(0))
+
+        bitmap2d = lax.fori_loop(
+            0, k_pad, body1, jnp.zeros((n, p_dim), dtype=jnp.uint32)
+        )
+        return bitmap2d[:, :, None]
+
+    def body(k, bitmap):
+        on, slot, bit = slot_bits(k)
         word_sel = (jnp.maximum(slot, 0) // 32)[:, None] == jnp.arange(
             n_words
         )[None, :]  # [N, W]
-        contrib = jnp.where(
+        return bitmap | jnp.where(
             on[:, :, None] & word_sel[:, None, :],
             bit[:, None, None],
             jnp.uint32(0),
         )
-        bitmap = bitmap | contrib
-    return bitmap
+
+    return lax.fori_loop(
+        0, k_pad, body, jnp.zeros((n, p_dim, n_words), dtype=jnp.uint32)
+    )
 
 
 def reduced_all_sources(

@@ -19,26 +19,30 @@ of arxiv 2310.03983, PAPERS.md):
    out-slot): a residual slot k contributes `bg.resid_nbr[:, k]`, a
    band of offset c contributes the roll written as the gather
    `(v - c) mod N`, which makes the band and residual relaxes the SAME
-   kernel statement.
+   kernel statement.  Interpreter only: the per-group row gather has no
+   Mosaic lowering, so the default policy keeps it off on the TPU
+   (EPILOGUE_COMPILED_REFUSAL) until it is rewritten or deleted.
 
 2. `blocked_outer_pallas` — phase 3 of the blocked APSP rung
    (`parallel.blocked.blocked_outer`): the rank-B outer update
    `d[i, j] = min(d[i, j], min_m(col[i, m] + row[m, j]))` over
-   [tile_i, tile_j] VMEM blocks with the col/row panels streamed in per
-   grid row/column.  The drain mask is folded into the row panel before
-   the call (`row[m, :] = INF` where lane m is overloaded) — bit-exact
-   because `min(c + INF, INF) == INF` in the saturating uint32 domain
-   (operands <= 2^30, the add never wraps).
+   [128, 128] VMEM blocks with the col/row panels streamed in 128-wide
+   chunks of the m axis.  The drain mask is folded into the row panel
+   in the kernel (`row[m, :] = INF` where lane m is overloaded) —
+   bit-exact because `min(c + INF, INF) == INF` in the saturating
+   domain (operands <= 2^30).  Compiles for v5e
+   (tests/test_tpu_compile.py) but is off by default
+   (OUTER_DEFAULT_REFUSAL): no default tile policy hands it the
+   128-multiple tiles Mosaic needs.
 
-Fallback contract (same as the blocked rung): these kernels are an
-OPTIONAL acceleration, never a dependency.  `run_with_fallback` demotes
-to the caller-supplied XLA thunk on ANY Pallas unavailability, shape or
-tile mismatch (the conformance gates below raise ValueError at trace
-time, before any buffer is donated), or injected chaos fault, with
-`device.engine.pallas_fallbacks` accounted; `OPENR_PALLAS=0` skips the
-attempt entirely (`device.engine.pallas_skips`).  Tier-1 proves
-bit-exactness against the lax kernels with `interpret=True` on CPU;
-compiled mode engages only on a real TPU backend.
+Dispatch contract (`run_with_fallback`): a kernel that is off, or
+that the compiler would refuse, is a counted skip
+(`device.engine.pallas_skips`) decided before dispatch (the blocked
+rung decides its tile conformance once per closure, `outer_conformance`).  A compiled
+launch never demotes: a failure there raises.  Only interpret mode, the
+CPU correctness tool, demotes to the XLA thunk on failure (the chaos
+seam's contract, `device.engine.pallas_fallbacks`).  Tier-1 proves
+bit-exactness against the lax kernels with `interpret=True` on CPU.
 
 Bit-exactness argument, epilogue: padding rows/columns carry the INF
 sentinel and padded group rows carry wbig weights, so padded candidates
@@ -64,13 +68,7 @@ from jax import lax
 
 from .sssp import INF16, INF32
 
-try:  # pallas is part of jax, but keep the no-hard-dependency contract
-    from jax.experimental import pallas as pl
-
-    _PALLAS_IMPORT_ERROR: Exception | None = None
-except Exception as _exc:  # pragma: no cover - import guard
-    pl = None  # type: ignore[assignment]
-    _PALLAS_IMPORT_ERROR = _exc
+from jax.experimental import pallas as pl
 
 log = logging.getLogger(__name__)
 
@@ -81,25 +79,38 @@ _WBIG16 = 20000  # ops.sssp.WBIG16
 _INF32 = int(INF32)  # 1 << 30
 _WBIG32 = 1 << 28  # ops.banded.WBIG
 
-# per-instance VMEM we are willing to ask Mosaic for before demoting;
-# real TPUs have ~16 MiB and the compiler needs headroom
-_VMEM_BUDGET = 12 * 1024 * 1024
-
 
 # -- policy -------------------------------------------------------------------
+
+# Neither kernel is on the TPU default ("auto" resolves to "off" on every
+# backend); each is opt-in with OPENR_PALLAS=1/compiled.
+#
+# The fused epilogue's per-group row gather (`jnp.take(d, idx, axis=0)`)
+# has no Mosaic lowering, and at fat-tree-10k widths its [N_pad, 128]
+# distance + bitmap tiles alone overrun the VMEM budget, so even an
+# explicit compiled request is refused before dispatch as a counted skip.
+EPILOGUE_COMPILED_REFUSAL = (
+    "fused epilogue: the per-group row gather has no Mosaic lowering"
+)
+# The blocked outer kernel compiles for v5e and ran bit-exact on the chip
+# when forced, but no default path hands it the 128-multiple tiles Mosaic
+# needs: the blocked rung picks B=16 on a one-device mesh, and sharded
+# meshes keep the collective-aware XLA kernel.
+OUTER_DEFAULT_REFUSAL = (
+    "blocked outer: no default tile policy yields 128-multiple tiles"
+)
 
 
 def pallas_mode(env: str | None = None) -> str:
     """Resolve the OPENR_PALLAS knob to "off" | "interpret" | "compiled".
 
-    Default (unset / "auto"): compiled on a TPU backend, off elsewhere —
-    the interpreter is a correctness tool, not a fast path, so it never
-    engages implicitly.  "1"/"on" forces the kernels on (compiled on
-    TPU, interpreter elsewhere); "0"/"off" forces them off;
-    "interpret"/"compiled" pin the execution mode explicitly (tests and
-    the program auditor use "interpret" on CPU)."""
-    if pl is None:
-        return "off"
+    Default (unset / "auto"): off on every backend (EPILOGUE_COMPILED_
+    REFUSAL, OUTER_DEFAULT_REFUSAL); the interpreter is a correctness
+    tool, not a fast path, so it never engages implicitly either.
+    "1"/"on" forces the kernels on (compiled on TPU, interpreter
+    elsewhere); "0"/"off" forces them off; "interpret"/"compiled" pin
+    the execution mode explicitly (tests and the program auditor use
+    "interpret" on CPU)."""
     v = (env if env is not None else os.environ.get("OPENR_PALLAS", "")) or ""
     v = v.strip().lower()
     if v in ("0", "off"):
@@ -108,12 +119,19 @@ def pallas_mode(env: str | None = None) -> str:
         return "interpret"
     if v == "compiled":
         return "compiled"
-    on_tpu = jax.default_backend() == "tpu"
     if v in ("1", "on"):
-        return "compiled" if on_tpu else "interpret"
+        return "compiled" if jax.default_backend() == "tpu" else "interpret"
     if v not in ("", "auto"):
         log.warning("OPENR_PALLAS=%r not understood; treating as auto", v)
-    return "compiled" if on_tpu else "off"
+    return "off"
+
+
+def count_skip(counters, kind: str, reason: str) -> None:
+    """Account one kernel launch not taken (`pallas_skips`), with why."""
+    counters["device.engine.pallas_skips"] = (
+        counters.get("device.engine.pallas_skips", 0) + 1
+    )
+    log.debug("pallas %s kernel skipped: %s", kind, reason)
 
 
 def run_with_fallback(
@@ -125,8 +143,8 @@ def run_with_fallback(
     fault_hook=None,
     mode: str | None = None,
 ):
-    """Run `pallas_thunk(interpret: bool)` under the graceful-demotion
-    contract, or `xla_thunk()` when Pallas is off or fails.
+    """Run `pallas_thunk(interpret: bool)`, or `xla_thunk()` when the
+    kernel is off or refused.
 
     `kind` is "product" (fused epilogue) or "outer" (blocked rank-B
     update) and selects the success counter.  `counters`/`fault_hook`
@@ -135,46 +153,49 @@ def run_with_fallback(
     accounting.  `mode` overrides the env policy (tests and the program
     auditor pass "interpret" instead of mutating the environment).
 
-    The chaos gate fires INSIDE the try block — an armed
-    `engine:pallas` fault demotes through the exact path a real Pallas
-    failure takes, fallbacks counter included."""
+    Off, and a compiled epilogue (EPILOGUE_COMPILED_REFUSAL), are a
+    counted skip decided before dispatch.  A compiled launch that fails
+    raises — compiled kernels never demote silently, so a device failure
+    shows as one.  Only the interpreter (a CPU correctness tool) keeps
+    the demotion path: there any failure, the armed `engine:pallas`
+    chaos fault included, re-runs `xla_thunk` and bumps
+    `pallas_fallbacks`."""
     eff = mode if mode is not None else pallas_mode()
+    if counters is None:
+        counters = {}  # engine-less caller: policy only, no accounting
     if eff == "off":
-        if counters is not None:
-            counters["device.engine.pallas_skips"] = (
-                counters.get("device.engine.pallas_skips", 0) + 1
-            )
+        count_skip(counters, kind, "off")
         return xla_thunk()
-    try:
-        if fault_hook is not None:
-            fault_hook("pallas")
-        out = pallas_thunk(eff == "interpret")
-    except Exception:
-        if counters is not None:
+    if eff == "compiled" and kind == "product":
+        count_skip(counters, kind, EPILOGUE_COMPILED_REFUSAL)
+        return xla_thunk()
+    if eff == "interpret":
+        try:
+            if fault_hook is not None:
+                fault_hook("pallas")
+            out = pallas_thunk(True)
+        except Exception:
             counters["device.engine.pallas_fallbacks"] = (
                 counters.get("device.engine.pallas_fallbacks", 0) + 1
             )
-        log.warning(
-            "pallas %s kernel demoted to the XLA path", kind, exc_info=True
+            log.warning(
+                "pallas %s kernel demoted to the XLA path", kind,
+                exc_info=True,
+            )
+            return xla_thunk()
+    else:
+        if fault_hook is not None:
+            fault_hook("pallas")
+        out = pallas_thunk(False)
+    if kind == "product":
+        counters["device.engine.pallas_products"] = (
+            counters.get("device.engine.pallas_products", 0) + 1
         )
-        return xla_thunk()
-    if counters is not None:
-        if kind == "product":
-            counters["device.engine.pallas_products"] = (
-                counters.get("device.engine.pallas_products", 0) + 1
-            )
-        else:
-            counters["device.engine.pallas_outer_updates"] = (
-                counters.get("device.engine.pallas_outer_updates", 0) + 1
-            )
+    else:
+        counters["device.engine.pallas_outer_updates"] = (
+            counters.get("device.engine.pallas_outer_updates", 0) + 1
+        )
     return out
-
-
-def _require_pallas() -> None:
-    if pl is None:  # pragma: no cover - exercised only without pallas
-        raise RuntimeError(
-            f"jax.experimental.pallas unavailable: {_PALLAS_IMPORT_ERROR!r}"
-        )
 
 
 def _round_up(x: int, m: int) -> int:
@@ -252,7 +273,6 @@ def fused_epilogue_pallas(
     column tiles, group tables resident per instance.  Returns
     (bitmap [W, Np, Pp] uint32, vmin [Np, Pp] ddt); the caller slices
     off the padding and reduces `all(vmin == d)` for the verdict."""
-    _require_pallas()
     np_pad, pp = d.shape
     gp = idx.shape[0]
     small = d.dtype == jnp.uint16
@@ -260,17 +280,7 @@ def fused_epilogue_pallas(
     wbig = _WBIG16 if small else _WBIG32
     tp = 128
     if not interpret:
-        # per-instance VMEM: d tile + vmin tile + bitmap words + tables
-        vmem = (
-            np_pad * tp * (2 * d.dtype.itemsize + n_words * 4)
-            + 4 * gp * np_pad * 4
-        )
-        if vmem > _VMEM_BUDGET:
-            raise ValueError(
-                f"pallas epilogue: {vmem} B VMEM per instance exceeds the "
-                f"{_VMEM_BUDGET} B budget (N_pad={np_pad}, groups={gp}, "
-                f"words={n_words}) — demote to the XLA epilogue"
-            )
+        raise ValueError(EPILOGUE_COMPILED_REFUSAL)
     kernel = functools.partial(
         _epilogue_kernel,
         n_groups=n_groups,
@@ -373,26 +383,48 @@ def fused_epilogue(ops, bg, d, resid_slot, band_slot, n_words, *, interpret):
 # -- kernel 2: blocked rank-B outer update ------------------------------------
 
 
-def _outer_kernel(d_ref, c_ref, r_ref, ov_ref, o_ref, *, b: int):
-    """One [ti, tj] distance tile: rank-B saturating min-plus update
-    from the resident [ti, B] col / [B, tj] row panel blocks.  The
-    drain mask lands HERE, in the kernel prologue — row m of the row
-    panel block lifts to INF when lane m of tile k is overloaded — so
-    the launch consumes the raw panels the moment they land (the
-    pipelined round hands them straight off the prefetch) instead of
-    waiting on a masked staging copy."""
-    d = d_ref[0]
-    c = c_ref[0]
-    infu = jnp.uint32(_INF32)
-    ov = ov_ref[0]  # [B] int32 drain lanes of tile k
-    r = jnp.where(ov[:, None] != 0, infu, r_ref[0])
+def outer_conformance(s: int, t: int, b: int) -> str | None:
+    """Why the compiled outer kernel cannot take tiles (S, T, B), or None.
 
-    def body(m, acc):
-        cm = lax.dynamic_slice_in_dim(c, m, 1, axis=1)  # [ti, 1]
-        rm = lax.dynamic_slice_in_dim(r, m, 1, axis=0)  # [1, tj]
-        return jnp.minimum(acc, jnp.minimum(cm + rm, infu))
+    Mosaic needs 128-multiple lane dims for the [ti, tj] / [ti, 128]
+    blocks (ti = 128, the m axis chunked by 128).  The blocks are fixed
+    at that size whatever S, T and B are (about 0.6 MiB of VMEM
+    double-buffered), so the tile is the only refusal.  Decided once per
+    closure by the blocked rung (`BlockedApspEngine.run_apsp`, a counted
+    skip), and asserted again by a direct compiled launch."""
+    if b % 128:
+        return (
+            f"blocked outer: tile B={b} is not a multiple of 128 "
+            f"(Mosaic lane tiling)"
+        )
+    return None
 
-    o_ref[0] = lax.fori_loop(0, b, body, d)
+
+def _outer_kernel(d_ref, c_ref, r_ref, ov_ref, o_ref, *, kb: int):
+    """One [ti, tj] distance tile, one kb-wide chunk of the rank-B
+    update (grid axis 3 walks the chunks and revisits the output tile).
+
+    Mosaic has no dynamic lane slice, so the m loop is unrolled over the
+    chunk: column m of the col block is a static lane slice, row m of
+    the row block a static sublane slice of its ref.  The drain table
+    runs m over sublanes (`ov_ref[m, :]` all equal), so lifting row m to
+    INF where lane m is overloaded is a sublane read too.  Everything is
+    int32 (Mosaic has no unsigned min): operands lie in [0, 2^30], and
+    `r + min(c, INF - r)` is the saturating `min(c + r, INF)` without an
+    intermediate that could wrap."""
+
+    @pl.when(pl.program_id(3) == 0)
+    def _():
+        o_ref[...] = d_ref[...]
+
+    infu = jnp.int32(_INF32)
+    c = c_ref[0]  # [ti, kb], m over lanes
+    acc = o_ref[0]
+    for m in range(kb):
+        rm = r_ref[0, m : m + 1, :]  # [1, tj]
+        rm = jnp.where(ov_ref[m : m + 1, 0:1] != 0, infu, rm)
+        acc = jnp.minimum(acc, rm + jnp.minimum(c[:, m : m + 1], infu - rm))
+    o_ref[0] = acc
 
 
 @functools.partial(
@@ -406,58 +438,46 @@ def blocked_outer_pallas(
     write-back in XLA, then the rank-B outer update as a tiled kernel
     over the [Np, Np] view of the tile tensor.
 
-    The drain mask folds into the kernel PROLOGUE (`_outer_kernel`
-    lifts row m of the row-panel block to INF where lane m of tile k
-    is overloaded): bit-exact against the per-m `where(ov_m, INF,
-    cand)` of the XLA kernel because `min(c + INF, INF) == INF` and
-    uint32 never wraps for operands <= 2^30.  Integer min is exact and
-    order-free, so the m-loop accumulation matches XLA's bit for bit.
-    Keeping the mask out of the host-side prep means no staging copy
-    of the panels sits between the (possibly prefetched) panel landing
-    and the launch.
+    The drain mask folds into the kernel (`_outer_kernel` lifts row m of
+    the row-panel block to INF where lane m of tile k is overloaded):
+    bit-exact against the per-m `where(ov_m, INF, cand)` of the XLA
+    kernel because `min(c + INF, INF) == INF`.  Integer min is exact and
+    order-free, so the chunked m accumulation matches XLA's bit for bit.
+    The uint32 tensors enter and leave as int32 bitcasts (free; values
+    never exceed 2^30).
 
-    Donation note: `dist` is donated (matching `blocked_outer`).  Every
-    demotion trigger — conformance gates below, Mosaic lowering errors,
-    the armed chaos fault (fired before this call) — raises at or
-    before trace time, so the fallback re-runs on an intact buffer."""
-    _require_pallas()
+    Donation note: `dist` is donated (matching `blocked_outer`); the
+    conformance gate below raises at trace time, before any buffer is
+    consumed."""
     s, t, b = dist.shape[0], dist.shape[1], dist.shape[2]
     np_ = t * b
+    if not interpret:
+        reason = outer_conformance(s, t, b)
+        if reason is not None:
+            raise ValueError(reason)
     dist = lax.dynamic_update_index_in_dim(dist, row_p, k, axis=1)
     dist = lax.dynamic_update_index_in_dim(dist, col_p, k, axis=3)
     ov = lax.dynamic_slice_in_dim(node_overloaded, k * b, b)  # [B] bool
-    rm = row_p.reshape(s, b, np_)
-    cm = col_p.reshape(s, np_, b)
-    # [8, B] int32 mask table (8 sublanes for Mosaic conformance; the
-    # kernel reads row 0)
-    ovt = jnp.zeros((8, b), jnp.int32).at[0].set(ov.astype(jnp.int32))
-    d2 = dist.reshape(s, np_, np_)  # tile dims are contiguous: free view
+    i32 = functools.partial(lax.bitcast_convert_type, new_dtype=jnp.int32)
+    rm = i32(row_p.reshape(s, b, np_))
+    cm = i32(col_p.reshape(s, np_, b))
+    d2 = i32(dist.reshape(s, np_, np_))  # tile dims contiguous: free view
+    # [B, 128] drain table, m over sublanes (every lane of row m equal)
+    ovt = jnp.broadcast_to(ov.astype(jnp.int32)[:, None], (b, 128))
     ti = 128 if np_ % 128 == 0 else b
-    if not interpret and (ti % 128 or b % 128):
-        # Mosaic tile conformance: the [ti, tj] / [ti, B] / [B, tj]
-        # blocks need 128-multiple lanes (and 8-multiple sublanes, which
-        # 128 covers); anything smaller demotes rather than mis-tiles
-        raise ValueError(
-            f"pallas blocked outer: tiles (ti={ti}, B={b}) are not "
-            f"Mosaic-conformant (need multiples of 128) — demote to XLA"
-        )
-    if not interpret and 4 * (2 * ti * ti + 2 * ti * b + 8 * b) > _VMEM_BUDGET:
-        raise ValueError(
-            f"pallas blocked outer: tile ti={ti}, B={b} exceeds the "
-            f"{_VMEM_BUDGET} B VMEM budget — demote to XLA"
-        )
+    kb = 128 if b % 128 == 0 else b
     out = pl.pallas_call(
-        functools.partial(_outer_kernel, b=b),
-        grid=(s, np_ // ti, np_ // ti),
+        functools.partial(_outer_kernel, kb=kb),
+        grid=(s, np_ // ti, np_ // ti, b // kb),
         in_specs=[
-            pl.BlockSpec((1, ti, ti), lambda si, i, j: (si, i, j)),
-            pl.BlockSpec((1, ti, b), lambda si, i, j: (si, i, 0)),
-            pl.BlockSpec((1, b, ti), lambda si, i, j: (si, 0, j)),
-            pl.BlockSpec((8, b), lambda si, i, j: (0, 0)),
+            pl.BlockSpec((1, ti, ti), lambda si, i, j, q: (si, i, j)),
+            pl.BlockSpec((1, ti, kb), lambda si, i, j, q: (si, i, q)),
+            pl.BlockSpec((1, kb, ti), lambda si, i, j, q: (si, q, j)),
+            pl.BlockSpec((kb, 128), lambda si, i, j, q: (q, 0)),
         ],
-        out_specs=pl.BlockSpec((1, ti, ti), lambda si, i, j: (si, i, j)),
-        out_shape=jax.ShapeDtypeStruct((s, np_, np_), jnp.uint32),
+        out_specs=pl.BlockSpec((1, ti, ti), lambda si, i, j, q: (si, i, j)),
+        out_shape=jax.ShapeDtypeStruct((s, np_, np_), jnp.int32),
         input_output_aliases={0: 0},
         interpret=interpret,
     )(d2, cm, rm, ovt)
-    return out.reshape(s, t, b, t, b)
+    return lax.bitcast_convert_type(out, jnp.uint32).reshape(s, t, b, t, b)

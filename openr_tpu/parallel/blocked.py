@@ -685,25 +685,29 @@ class BlockedApspEngine:
         # launching it on a sharded tile tensor would all-gather the
         # matrix.  The parent engine owns the policy, the
         # device.engine.pallas_* accounting and the chaos seam; a
-        # standalone rung (no parent) always takes the XLA phase.
-        run_pallas = (
-            getattr(self._parent, "run_pallas", None)
-            if mesh.devices.size == 1
-            else None
-        )
-        # the split lookahead+outer rounds exist only to order the
-        # Pallas donation; when the kernels resolve to "off" the
-        # pipelined loop keeps the fused blocked_round_pipelined root
-        # (the epilogue still dispatches through run_pallas, so the
-        # pallas_skips accounting survives)
-        split_rounds = False
-        if run_pallas is not None:
+        # standalone rung (no parent) always takes the XLA phase.  The
+        # launch is decided here, once per closure: off, or tiles the
+        # compiled kernel refuses, is one counted skip and the XLA phase
+        # for every round (the pipelined loop then keeps its fused
+        # blocked_round_pipelined root; the split lookahead+outer rounds
+        # exist only to order the Pallas donation).
+        run_pallas = None
+        parent = self._parent
+        if mesh.devices.size == 1 and parent is not None:
             from ..ops import pallas_kernels as pk
 
-            eff = getattr(self._parent, "pallas_mode", None)
-            split_rounds = (
-                eff if eff is not None else pk.pallas_mode()
-            ) != "off"
+            eff = parent.pallas_mode
+            eff = eff if eff is not None else pk.pallas_mode()
+            reason = "off" if eff == "off" else None
+            if eff == "compiled":
+                reason = pk.outer_conformance(s, t, b)
+            if reason is None:
+                run_pallas = parent.run_pallas
+            else:
+                pk.count_skip(parent.counters, "outer", reason)
+                if tr is not None:
+                    tr.annotate("engine.kernel", "outer:xla")
+        split_rounds = run_pallas is not None
         if self.pipeline_enabled(t):
             dist = jax.device_put(dist0.reshape(s, t, b, t, b), s_dist)
             try:
@@ -726,7 +730,8 @@ class BlockedApspEngine:
 
     def _outer_step(self, dist, row_p, col_p, ov, kk, mesh, run_pallas):
         """Round-k phase 3 through the dispatch rung: Pallas with the
-        XLA thunk as the demotion target, or plain `blocked_outer`."""
+        XLA thunk as the (interpret-mode) demotion target, or plain
+        `blocked_outer`."""
         if run_pallas is not None:
             # every demotion trigger raises at/before trace time
             # (pallas_kernels.blocked_outer_pallas docstring), so

@@ -57,6 +57,8 @@ _OPCODE_RE = re.compile(r"^([a-z][\w-]*)\(")
 #: rank-5 u32 per-shard array — the blocked outer update's carry type;
 #: no other while in the fused round carries a 5-D operand
 _RANK5_U32_RE = re.compile(r"u32\[\d+,\d+,\d+,\d+,\d+\]")
+#: the CPU compiler's call wrapper around a small while loop
+_WHILE_CALL_RE = re.compile(r"to_apply=%while[\w.-]*_computation\b")
 
 
 @dataclass
@@ -169,11 +171,19 @@ def _closure(edges: dict[str, list[str]], seeds: list[str]) -> set[str]:
     return out
 
 
+def _is_loop(i: Instr) -> bool:
+    """A while loop of the entry: a `while`, or the `call` the CPU
+    compiler wraps a small while into (`to_apply=%while.N_computation`)."""
+    return i.opcode == "while" or (
+        i.opcode == "call" and _WHILE_CALL_RE.search(i.attrs) is not None
+    )
+
+
 def find_outer_update(instrs: list[Instr]) -> str | None:
     """The round-k outer update: the only while whose carry holds the
     rank-5 u32 tile tensor."""
     for i in instrs:
-        if i.opcode == "while" and _RANK5_U32_RE.search(i.shape):
+        if _is_loop(i) and _RANK5_U32_RE.search(i.shape):
             return i.name
     return None
 
@@ -185,8 +195,10 @@ def materialize(text: str) -> tuple[str, list[dict]]:
     after every ready independent op).  Returns (materialized entry
     text, span report).
 
-    Legality is the async scheduler's rule, checked per span from the
-    parsed def-use graph: an op between start and done must be neither
+    Starts are hoisted as early as their operands allow, and the
+    producers of gather operands are listed ahead of independent work,
+    as a latency-hiding scheduler orders them.  Legality is the async
+    scheduler's rule, checked per span from the parsed def-use graph: an op between start and done must be neither
     a transitive producer of the gather's operands nor a transitive
     consumer of its result.  The list schedule is a topological order
     by construction, and dones are emitted only when every remaining
@@ -199,18 +211,25 @@ def materialize(text: str) -> tuple[str, list[dict]]:
     # node graph with each gather split into start (the gather's deps)
     # and done (the start); users of the gather now consume the done,
     # which keeps every other instruction line textually unchanged
+    # priorities, latency-hiding style: starts issue the moment their
+    # operands exist, the producers of gather operands run before other
+    # work, everything else keeps its compiled order (the compiler's own
+    # schedule may put an independent loop ahead of a gather's inputs)
+    feeds = _closure({i.name: i.deps for i in instrs}, [
+        d for g in gathers for d in g.deps
+    ])
     deps: dict[str, list[str]] = {}
     prio: dict[str, tuple[int, int]] = {}
     done_names = {g.name for g in gathers}
     for i in instrs:
         if i.name in done_names:
             deps[i.name + "-start"] = list(i.deps)
-            prio[i.name + "-start"] = (i.index, 0)
+            prio[i.name + "-start"] = (0, i.index)
             deps[i.name] = [i.name + "-start"]
-            prio[i.name] = (i.index, 1)
+            prio[i.name] = (2, i.index)
         else:
             deps[i.name] = list(i.deps)
-            prio[i.name] = (i.index, 0)
+            prio[i.name] = (1 if i.name in feeds else 2, i.index)
 
     emitted: set[str] = set()
     order: list[str] = []
@@ -271,7 +290,8 @@ def materialize(text: str) -> tuple[str, list[dict]]:
         compute = [
             n
             for n in inside
-            if by_name.get(n) and by_name[n].opcode in ("while", "fusion")
+            if by_name.get(n)
+            and (_is_loop(by_name[n]) or by_name[n].opcode == "fusion")
         ]
         spans.append(
             {
@@ -310,7 +330,9 @@ def async_report(text: str) -> dict:
     covered: set[str] = set()
     for s in spans:
         covered.update(s["compute_in_span"])
-    compute = [i.name for i in instrs if i.opcode in ("while", "fusion")]
+    compute = [
+        i.name for i in instrs if _is_loop(i) or i.opcode == "fusion"
+    ]
     frac = 100 * len([c for c in compute if c in covered]) // max(len(compute), 1)
     outer_spanning = len(
         [s for s in spans if s["spans_outer_update"] and s["legal"]]

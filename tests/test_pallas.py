@@ -227,9 +227,9 @@ class TestPolicyAndFallback:
         assert pk.pallas_mode(env="1") == (
             "compiled" if on_tpu else "interpret"
         )
-        # auto: compiled on TPU, off elsewhere (the interpreter is a
-        # correctness tool, never an implicit fast path)
-        assert pk.pallas_mode(env="") == ("compiled" if on_tpu else "off")
+        # auto: off everywhere (neither kernel is on the TPU default;
+        # the interpreter is a correctness tool, never an implicit path)
+        assert pk.pallas_mode(env="") == "off"
         assert pk.pallas_mode(env="auto") == pk.pallas_mode(env="")
         assert pk.pallas_mode(env="bogus") == pk.pallas_mode(env="auto")
 
