@@ -1,0 +1,8 @@
+"""Fib layer: mean duration of the fib.program stage of each
+kvstore.publication trace."""
+
+from perf.layer_metrics._spans import stage_ms
+
+
+def read(ctx):
+    return stage_ms(ctx, "kvstore.publication", "fib.program")
