@@ -13,18 +13,40 @@ Dataclass values are wire-tagged via serializer.to_wire/from_wire.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import fnmatch
 import json
 import logging
 import re
 from typing import Any, Awaitable, Callable, Optional
 
+from ..obs import trace as _trace
 from ..runtime.eventbase import OpenrEventBase
 from ..runtime.queue import QueueClosedError, ReplicateQueue
 from ..serializer import from_wire, to_wire
 from ..types import ADJ_MARKER, Publication
 
 log = logging.getLogger(__name__)
+
+# OPENR_TRACE: the "ctrl.reply" root of the serving query a connection
+# task is answering.  The handler opens it (the task's own context); the
+# connection finishes it once the reply line is written and drained.
+_REPLY_SPAN: contextvars.ContextVar = contextvars.ContextVar(
+    "ctrl_reply_span", default=None
+)
+
+
+def _open_reply_span(op: str, res) -> None:
+    """Open the reply's root at the scheduler's `t_done`: the ctrl
+    loop's wake-up, value shaping, `to_wire`, JSON encode and write.  A
+    root of its own, so `serving.query` stays one."""
+    tr = _trace.TRACE
+    if tr is None or not res.t_done:
+        return
+    sp = tr.root("ctrl.reply", op=op)
+    if sp is not None:
+        sp.t_start_us = int(res.t_done * 1e6)  # Span's clock: perf_counter
+        _REPLY_SPAN.set(sp)
 
 OPENR_VERSION = 20
 OPENR_LOWEST_SUPPORTED_VERSION = 20
@@ -361,6 +383,7 @@ class OpenrCtrlHandler:
             **kw,
         )
         res = await asyncio.wrap_future(fut)
+        _open_reply_span(op, res)
         return {
             "result": self._shape_query_value(op, res.value),
             "epoch": res.epoch,
@@ -385,6 +408,7 @@ class OpenrCtrlHandler:
             steps=p.get("steps", 32),
         )
         res = await asyncio.wrap_future(fut)
+        _open_reply_span("optimize_metrics", res)
         return {
             "result": res.value,
             "epoch": res.epoch,
@@ -766,6 +790,10 @@ class CtrlServer(OpenrEventBase):
         try:
             result = await afn(params)
             await send({"id": msg_id, "result": to_wire(result)})
+            sp = _REPLY_SPAN.get(None)
+            tr = _trace.TRACE
+            if sp is not None and tr is not None:
+                tr.finish(sp)  # the reply line is written and drained
         except (asyncio.CancelledError, ConnectionResetError):
             pass
         except Exception as e:  # noqa: BLE001

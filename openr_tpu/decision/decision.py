@@ -40,6 +40,10 @@ from .spf_solver import HostSpfBackend, SpfBackend, SpfSolver
 
 FIB_TIME_MARKER = "fibTime:"
 
+# pre-seeded into Decision.counters so both wire surfaces expose them
+# from daemon start
+DECISION_COUNTER_KEYS = ("decision.rebuilds",)
+
 
 class DecisionPendingUpdates:
     """Reference: detail::DecisionPendingUpdates
@@ -168,7 +172,7 @@ class Decision(OpenrEventBase):
         # and awaiting the (debounced) rebuild that folds them in.
         # Eventbase-thread only — no lock needed.
         self._trace_pending: list = []
-        self.counters: dict[str, int] = {}
+        self.counters: dict[str, int] = {k: 0 for k in DECISION_COUNTER_KEYS}
 
     def _bump(self, counter: str, n: int = 1) -> None:
         self.counters[counter] = self.counters.get(counter, 0) + n
@@ -378,20 +382,13 @@ class Decision(OpenrEventBase):
             # fan-in: the debounced rebuild folds every carried
             # publication at once — open a "decision" stage under each
             # and activate them all so the route push carries them on
-            spans = [
-                tr.child_open(sp, "decision", event=event)
-                for sp in dict.fromkeys(pending)
-            ]
-            try:
-                with tr.activate(spans):
-                    self._rebuild_routes_impl(event)
-            finally:
-                for sp in spans:
-                    sp.finish()
+            with tr.fan_in(pending, "decision", event=event):
+                self._rebuild_routes_impl(event)
             return
         self._rebuild_routes_impl(event)
 
     def _rebuild_routes_impl(self, event: str) -> None:
+        self._bump("decision.rebuilds")
         self.pending_updates.add_event(event)
 
         try:
@@ -422,22 +419,25 @@ class Decision(OpenrEventBase):
     def _compute_route_update(self) -> DecisionRouteUpdate:
         update = DecisionRouteUpdate()
         if self.pending_updates.needs_full_rebuild:
-            maybe_db = self.spf_solver.build_route_db(
-                self.area_link_states, self.prefix_state
-            )
+            with _trace.maybe_child("decision.route_build"):
+                maybe_db = self.spf_solver.build_route_db(
+                    self.area_link_states, self.prefix_state
+                )
             db = maybe_db if maybe_db is not None else DecisionRouteDb()
             if self.rib_policy is not None:
                 self.rib_policy.apply_policy(db.unicast_routes)
-            update = self.route_db.calculate_update(db)
+            with _trace.maybe_child("decision.route_diff"):
+                update = self.route_db.calculate_update(db)
         else:
-            for prefix in self.pending_updates.updated_prefixes:
-                route = self.spf_solver.create_route_for_prefix_or_get_static_route(
-                    self.area_link_states, self.prefix_state, prefix
-                )
-                if route is not None:
-                    update.add_route_to_update(route)
-                else:
-                    update.unicast_routes_to_delete.append(prefix)
+            with _trace.maybe_child("decision.route_build"):
+                for prefix in self.pending_updates.updated_prefixes:
+                    route = self.spf_solver.create_route_for_prefix_or_get_static_route(
+                        self.area_link_states, self.prefix_state, prefix
+                    )
+                    if route is not None:
+                        update.add_route_to_update(route)
+                    else:
+                        update.unicast_routes_to_delete.append(prefix)
             if self.rib_policy is not None:
                 changes = self.rib_policy.apply_policy(
                     update.unicast_routes_to_update

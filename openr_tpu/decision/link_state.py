@@ -27,6 +27,7 @@ import heapq
 from dataclasses import dataclass, field
 from typing import Generic, Iterable, Optional, TypeVar
 
+from ..obs import trace as _trace
 from ..types import Adjacency, AdjacencyDatabase
 
 INF = float("inf")
@@ -634,7 +635,9 @@ class LinkState:
         key = (node, use_link_metric)
         res = self._spf_results.get(key)
         if res is None:
-            res = self._spf_results[key] = self.run_spf(node, use_link_metric)
+            with _trace.maybe_child("decision.spf"):
+                res = self.run_spf(node, use_link_metric)
+            self._spf_results[key] = res
         return res
 
     def get_metric_from_a_to_b(

@@ -21,6 +21,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..obs import trace as _trace
 from ..ops.sssp import INF32
 from .csr import CsrTopology
 from .link_state import LinkState
@@ -57,6 +58,8 @@ def what_if(
     (callers default it to the querying router); passing None means every
     node, which is refused beyond a size budget — the [F, S, N] output is
     cubic-ish and this runs on the Decision event thread."""
+    import jax
+
     from ..ops import protection as prot
 
     if csr is None:
@@ -83,48 +86,55 @@ def what_if(
     )
 
     # row 0 = no-failure baseline, rows 1.. = scenarios: one device call
-    pair_ids = _pair_edge_ids(csr)
-    masks = np.ones((len(scenarios) + 1, csr.edge_capacity), dtype=bool)
-    resolved: list[dict] = []
-    for f, links in enumerate(scenarios):
-        known: list[list[str]] = []
-        unknown: list[list[str]] = []
-        for a, b in links:
-            key = (a, b) if a <= b else (b, a)
-            ids = pair_ids.get(key)
-            if ids:
-                masks[f + 1, ids] = False
-                known.append([a, b])
-            else:
-                unknown.append([a, b])
-        resolved.append({"links": known, "unknown_links": unknown})
+    with _trace.maybe_child("whatif.resolve"):
+        pair_ids = _pair_edge_ids(csr)
+        masks = np.ones((len(scenarios) + 1, csr.edge_capacity), dtype=bool)
+        resolved: list[dict] = []
+        for f, links in enumerate(scenarios):
+            known: list[list[str]] = []
+            unknown: list[list[str]] = []
+            for a, b in links:
+                key = (a, b) if a <= b else (b, a)
+                ids = pair_ids.get(key)
+                if ids:
+                    masks[f + 1, ids] = False
+                    known.append([a, b])
+                else:
+                    unknown.append([a, b])
+            resolved.append({"links": known, "unknown_links": unknown})
 
-    all_dist = prot.srlg_what_if(
-        src_ids,
-        csr.edge_src,
-        csr.edge_dst,
-        csr.edge_metric,
-        csr.edge_up,
-        csr.node_overloaded,
-        masks,
-        ell=csr.ell,
-    )
-    # restrict impact counting to real nodes (padding cols are unreachable
-    # in baseline too, so they never count, but be explicit)
-    real = np.asarray([csr.node_id[n] for n in csr.node_names])
-    # offline what-if analysis over one fixed scenario batch, not the SPF
-    # hot path — no residency or bucket ladder for the engine to apply
-    # openr: disable=jit-unbucketed-dispatch
-    unreachable, degraded = prot.srlg_reachability_loss(
-        all_dist[0][:, real], all_dist[1:][:, :, real]
-    )
-    out = []
-    for f in range(len(scenarios)):
-        row = dict(resolved[f])
-        row["scenario"] = f
-        row["newly_unreachable_pairs"] = int(unreachable[f])
-        row["degraded_pairs"] = int(degraded[f])
-        out.append(row)
+    with _trace.maybe_child("whatif.relax"):
+        all_dist = prot.srlg_what_if(
+            src_ids,
+            csr.edge_src,
+            csr.edge_dst,
+            csr.edge_metric,
+            csr.edge_up,
+            csr.node_overloaded,
+            masks,
+            ell=csr.ell,
+        )
+        if _trace.TRACE is not None:
+            # the span is the relax's device time, not its enqueue (the
+            # fetches below would wait for it anyway)
+            jax.block_until_ready(all_dist)
+    with _trace.maybe_child("whatif.reduce"):
+        # restrict impact counting to real nodes (padding cols are
+        # unreachable in baseline too, so they never count, but be explicit)
+        real = np.asarray([csr.node_id[n] for n in csr.node_names])
+        # offline what-if analysis over one fixed scenario batch, not the
+        # SPF hot path — no residency or bucket ladder for the engine
+        # openr: disable=jit-unbucketed-dispatch
+        unreachable, degraded = prot.srlg_reachability_loss(
+            all_dist[0][:, real], all_dist[1:][:, :, real]
+        )
+        out = []
+        for f in range(len(scenarios)):
+            row = dict(resolved[f])
+            row["scenario"] = f
+            row["newly_unreachable_pairs"] = int(unreachable[f])
+            row["degraded_pairs"] = int(degraded[f])
+            out.append(row)
     return out
 
 

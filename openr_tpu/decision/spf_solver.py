@@ -26,6 +26,7 @@ from typing import Optional, Protocol
 
 import numpy as np
 
+from ..obs import trace as _trace
 from ..types import (
     MplsAction,
     MplsActionCode,
@@ -286,8 +287,9 @@ class DeviceSpfBackend:
             for s in missing:
                 cache[s] = link_state.get_spf_result(s)
             return
-        csr = self._mirror(link_state)
-        cache.update(self._spf_from(csr, missing))
+        with _trace.maybe_child("decision.spf"):
+            csr = self._mirror(link_state)
+            cache.update(self._spf_from(csr, missing))
         self._harvest_hint(csr)
 
     def prefetch_via_mesh(
@@ -366,8 +368,10 @@ class DeviceSpfBackend:
             res = link_state.get_spf_result(src)
             cache[src] = res
             return res
-        csr = self._mirror(link_state)
-        cache.update(self._spf_from(csr, [src]))
+        # the miss: CSR mirror refresh, engine call and fetch
+        with _trace.maybe_child("decision.spf"):
+            csr = self._mirror(link_state)
+            cache.update(self._spf_from(csr, [src]))
         self._harvest_hint(csr)
         return cache[src]
 

@@ -198,7 +198,9 @@ def _forward_body(
     ops.sssp.spf_forward_full(_packed) but takes the donated distance
     scratch as its first argument so the runtime reuses its pages."""
 
-    def fn(
+    # a stable program name (the trace's `jit_spf_forward_resident`) and
+    # one named scope per phase, carried on the ops' metadata
+    def spf_forward_resident(
         dist0_T,  # [N_cap, S_bucket] int32 — DONATED
         sources,  # [S_bucket] int32
         ell,
@@ -209,31 +211,34 @@ def _forward_body(
         node_overloaded,
         out_slot,
     ):
-        dist_T, dist_ok = ops.batched_sssp_ell(
-            dist0_T,
-            ell,
-            unit_metric=not use_link_metric,
-            edge_up=edge_up,
-            node_overloaded=node_overloaded,
-            edge_metric=edge_metric,
-            n_sweeps=n_sweeps,
-        )
-        dist_old_T = ops.ell_dist_to_old_T(dist_T, ell)
-        metric = (
-            edge_metric if use_link_metric else jnp.ones_like(edge_metric)
-        )
-        allowed_T = ops.make_relax_allowed_T(
-            sources, edge_src, edge_up, node_overloaded
-        )
-        d_u = jnp.take(dist_old_T, edge_src, axis=0)
-        d_v = jnp.take(dist_old_T, edge_dst, axis=0)
-        dag_T = allowed_T & (d_u < ops.INF32) & (
-            d_u + metric[:, None] == d_v
-        )
-        nh, nh_ok = ops.first_hops_ell(
-            ell, dag_T, out_slot, sources, edge_src, n_words,
-            n_sweeps=n_sweeps,
-        )
+        with jax.named_scope("relax"):
+            dist_T, dist_ok = ops.batched_sssp_ell(
+                dist0_T,
+                ell,
+                unit_metric=not use_link_metric,
+                edge_up=edge_up,
+                node_overloaded=node_overloaded,
+                edge_metric=edge_metric,
+                n_sweeps=n_sweeps,
+            )
+        with jax.named_scope("sp_dag"):
+            dist_old_T = ops.ell_dist_to_old_T(dist_T, ell)
+            metric = (
+                edge_metric if use_link_metric else jnp.ones_like(edge_metric)
+            )
+            allowed_T = ops.make_relax_allowed_T(
+                sources, edge_src, edge_up, node_overloaded
+            )
+            d_u = jnp.take(dist_old_T, edge_src, axis=0)
+            d_v = jnp.take(dist_old_T, edge_dst, axis=0)
+            dag_T = allowed_T & (d_u < ops.INF32) & (
+                d_u + metric[:, None] == d_v
+            )
+        with jax.named_scope("first_hops"):
+            nh, nh_ok = ops.first_hops_ell(
+                ell, dag_T, out_slot, sources, edge_src, n_words,
+                n_sweeps=n_sweeps,
+            )
         ok = dist_ok & nh_ok
         if not small:
             # dist stays in the donated [N_cap, S] layout: the output aval
@@ -251,7 +256,7 @@ def _forward_body(
             ]
         )
 
-    return fn
+    return spf_forward_resident
 
 
 @dataclass

@@ -268,18 +268,13 @@ class Fib(OpenrEventBase):
                 # flap-path terminal: program the routes under a
                 # "fib.program" stage on each carried span, then finish
                 # every trace root (the publication entered the ring here)
-                spans = [
-                    tr.child_open(sp, "fib.program") for sp in carried
-                ]
                 try:
-                    with tr.activate(spans):
+                    with tr.fan_in(carried, "fib.program"):
                         try:
                             self.process_route_updates(update)
                         except Exception:
                             log.exception("fib: route update processing failed")
                 finally:
-                    for sp in spans:
-                        sp.finish()
                     for sp in carried:
                         tr.finish_root(sp)
                 continue

@@ -21,23 +21,32 @@ pattern), OpenrEventBase handoffs re-activate the captured scope on the
 loop thread, and batch execution activates EVERY coalesced query's span
 at once so one engine annotation lands on each (fan-in scope).
 
+Profiler clock: every LIVE span (:meth:`Tracer.child` and
+:meth:`Tracer.fan_in`) also runs its body inside one
+``jax.profiler.TraceAnnotation`` of the same name, so it lands on the
+thread's line of a profiler trace's ``/host:CPU`` plane, on the clock
+the device ops use.  Retroactive stages (:meth:`Tracer.stage`) stay
+host-clock only.
+
 Determinism contract: :meth:`Span.structure` serializes ONLY stage
 names, structural tags (engine rung, dispatch kind, outcome), and the
 child set — children sorted lexicographically, timers and ``note``
 metadata excluded — so same-seed chaos replays produce byte-identical
 structures and the fuzzer can ingest them as coverage tokens.
 
-This module never imports jax (or anything heavier than stdlib).
+This module never imports jax (or anything heavier than stdlib): the
+annotation is looked up in ``sys.modules`` when a live span opens.
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager, nullcontext
-from typing import Any, Callable, Iterator, Optional, Sequence
+from typing import Any, Callable, Iterable, Iterator, Optional, Sequence
 
 # Pre-seeded registry (analysis: counter-unbumped checks seeds vs bumps).
 OBS_COUNTER_KEYS = (
@@ -51,6 +60,17 @@ OBS_COUNTER_KEYS = (
 
 def _now_us() -> int:
     return time.perf_counter_ns() // 1_000
+
+
+_NULL = nullcontext()
+
+
+def _annotation(name: str):
+    """The profiler's host span for a live span: a
+    ``jax.profiler.TraceAnnotation`` once jax is loaded (a daemon armed
+    before jax loads still annotates later spans), else a no-op."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return _NULL if profiler is None else profiler.TraceAnnotation(name)
 
 
 class Span:
@@ -145,15 +165,24 @@ class Tracer:
         finally:
             self._tls.scope = prev
 
-    def bind_scope(self, fn: Callable[..., Any]) -> Callable[..., Any]:
+    def bind_scope(
+        self, fn: Callable[..., Any], loop: str = ""
+    ) -> Callable[..., Any]:
         """Capture the current scope for a closure about to be marshalled
-        to another thread (eventbase handoffs).  Identity when there is
-        nothing to carry."""
+        to another thread (eventbase handoffs), and record the time from
+        the handoff to the closure's start as an ``eventbase.wait`` stage
+        tagged with the receiving `loop`.  Identity when there is nothing
+        to carry."""
         scope = self.scope()
         if not scope:
             return fn
+        t_handoff = _now_us()
 
         def _with_scope(*args: Any, **kwargs: Any) -> Any:
+            # the closure's wait for the loop thread, on every carried span
+            t_run = _now_us()
+            for sp in scope:
+                self.stage(sp, "eventbase.wait", t_handoff, t_run, loop=loop)
             with self.activate(scope):
                 return fn(*args, **kwargs)
 
@@ -199,10 +228,23 @@ class Tracer:
         if not scope:
             yield
             return
-        kids = [self.child_open(sp, name, **tags) for sp in scope]
+        with self.fan_in(scope, name, **tags):
+            yield
+
+    @contextmanager
+    def fan_in(
+        self, parents: Iterable[Span], name: str, **tags: Any
+    ) -> Iterator[list]:
+        """Open a child `name` under each of `parents` (duplicates
+        folded), make the children the scope for the body, and finish
+        them on exit, raised or not.  The body runs inside ONE profiler
+        annotation `name`, however many parents fan in."""
+        kids = [
+            self.child_open(sp, name, **tags) for sp in dict.fromkeys(parents)
+        ]
         try:
-            with self.activate(kids):
-                yield
+            with _annotation(name), self.activate(kids):
+                yield kids
         finally:
             now = _now_us()
             for k in kids:
@@ -350,8 +392,6 @@ class ObsStats:
 # -- arming ------------------------------------------------------------------
 
 TRACE: Optional[Tracer] = None
-
-_NULL = nullcontext()
 
 
 def maybe_child(name: str, **tags: Any):

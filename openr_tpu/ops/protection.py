@@ -100,17 +100,18 @@ def _srlg_what_if_device(
         s_dim = sources.shape[0]
         flat_sources = jnp.tile(sources, f_dim)  # [F*S]
         flat_masks = jnp.repeat(scenario_masks, s_dim, axis=0)  # [F*S, E]
-        dist, _ = spf_forward_ell_masked(
-            flat_sources,
-            ell,
-            edge_src,
-            edge_dst,
-            edge_metric,
-            edge_up,
-            node_overloaded,
-            flat_masks,
-            want_dag=False,
-        )
+        with jax.named_scope("srlg_relax"):
+            dist, _ = spf_forward_ell_masked(
+                flat_sources,
+                ell,
+                edge_src,
+                edge_dst,
+                edge_metric,
+                edge_up,
+                node_overloaded,
+                flat_masks,
+                want_dag=False,
+            )
         return dist.reshape(f_dim, s_dim, n_nodes)
     base_allowed = make_relax_allowed(
         sources, edge_src, edge_up, node_overloaded
@@ -122,7 +123,8 @@ def _srlg_what_if_device(
             make_dist0(sources, n_nodes), edge_src, edge_dst, edge_metric, allowed
         )
 
-    return jax.lax.map(one_scenario, scenario_masks)
+    with jax.named_scope("srlg_relax"):
+        return jax.lax.map(one_scenario, scenario_masks)
 
 
 @jax.jit
@@ -131,15 +133,16 @@ def srlg_reachability_loss(
     scenario_dist: jax.Array,  # [F, S, N]
 ) -> tuple[jax.Array, jax.Array]:
     """Per scenario: (#newly-unreachable pairs, #degraded pairs)."""
-    was_reachable = baseline_dist < INF32
-    now_unreachable = was_reachable[None] & (scenario_dist >= INF32)
-    degraded = (
-        was_reachable[None]
-        & (scenario_dist < INF32)
-        & (scenario_dist > baseline_dist[None])
-    )
-    axes = (1, 2)
-    return now_unreachable.sum(axes), degraded.sum(axes)
+    with jax.named_scope("srlg_reduce"):
+        was_reachable = baseline_dist < INF32
+        now_unreachable = was_reachable[None] & (scenario_dist >= INF32)
+        degraded = (
+            was_reachable[None]
+            & (scenario_dist < INF32)
+            & (scenario_dist > baseline_dist[None])
+        )
+        axes = (1, 2)
+        return now_unreachable.sum(axes), degraded.sum(axes)
 
 
 def ti_lfa_backups(

@@ -31,13 +31,14 @@ def _tsan_handoff(fn: Callable[..., Any]) -> Callable[..., Any]:
     return fn if det is None else det.wrap_handoff(fn)
 
 
-def _obs_handoff(fn: Callable[..., Any]) -> Callable[..., Any]:
+def _obs_handoff(fn: Callable[..., Any], loop: str) -> Callable[..., Any]:
     """OPENR_TRACE: carry the caller's active span scope across the same
     thread handoff, so work marshalled onto a module loop keeps its
-    trace attribution.  Identity when disarmed (one attribute load) or
-    when the caller has no active scope."""
+    trace attribution (and records its wait for `loop`).  Identity when
+    disarmed (one attribute load) or when the caller has no active
+    scope."""
     tr = _trace.TRACE
-    return fn if tr is None else tr.bind_scope(fn)
+    return fn if tr is None else tr.bind_scope(fn, loop)
 
 
 def _sched_submit(eb: "OpenrEventBase") -> None:
@@ -49,10 +50,11 @@ def _sched_submit(eb: "OpenrEventBase") -> None:
         sc.handoff(eb)
 
 
-def _handoff(fn: Callable[..., Any]) -> Callable[..., Any]:
-    """Compose the cross-thread wrappers (trace innermost so the TSAN
-    handoff edge brackets the whole marshalled closure)."""
-    return _tsan_handoff(_obs_handoff(fn))
+def _handoff(fn: Callable[..., Any], loop: str) -> Callable[..., Any]:
+    """Compose the cross-thread wrappers for a closure bound for the
+    module loop named `loop` (trace innermost so the TSAN handoff edge
+    brackets the whole marshalled closure)."""
+    return _tsan_handoff(_obs_handoff(fn, loop))
 
 
 class Timeout:
@@ -172,7 +174,7 @@ class OpenrEventBase:
             self._loop.create_task(_graceful())
 
         try:
-            self._loop.call_soon_threadsafe(_handoff(_do_stop))
+            self._loop.call_soon_threadsafe(_handoff(_do_stop, self.name))
         except RuntimeError:
             return
         # Joining from the module's own loop thread would deadlock (the loop
@@ -220,7 +222,7 @@ class OpenrEventBase:
         def _create() -> None:
             self._track(self._loop.create_task(coro, name=name or "fiber"))
 
-        self._loop.call_soon_threadsafe(_handoff(_create))
+        self._loop.call_soon_threadsafe(_handoff(_create, self.name))
 
     def in_event_base_thread(self) -> bool:
         return threading.current_thread() is self._thread
@@ -251,7 +253,7 @@ class OpenrEventBase:
             except BaseException as e:  # noqa: BLE001
                 fut.set_exception(e)
 
-        self._loop.call_soon_threadsafe(_handoff(_call))
+        self._loop.call_soon_threadsafe(_handoff(_call, self.name))
         return fut
 
     async def run_async(self, coro: Awaitable[Any]) -> Any:
@@ -275,7 +277,10 @@ class OpenrEventBase:
         _sched_submit(self)
         token = Timeout()
         self._loop.call_soon_threadsafe(
-            _handoff(token._arm), self._loop, delay_s, _handoff(fn)
+            _handoff(token._arm, self.name),
+            self._loop,
+            delay_s,
+            _handoff(fn, self.name),
         )
         return token
 

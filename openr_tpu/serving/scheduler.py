@@ -97,12 +97,15 @@ class Query:
 
 @dataclass
 class QueryResult:
-    """Per-query reply with latency attribution."""
+    """Per-query reply with latency attribution.  `t_done` is the
+    `time.perf_counter()` at which the batch's answers were ready: the
+    ctrl server's reply span starts there."""
 
     value: Any
     latency_us: int
     batch_size: int
     epoch: int
+    t_done: float = 0.0
 
 
 @dataclass(eq=False)  # identity semantics: lives in the _inflight set
@@ -111,7 +114,8 @@ class _Pending:
     future: "concurrent.futures.Future[QueryResult]"
     t_submit: float
     # OPENR_TRACE only: the query's root span and the stage-boundary
-    # timestamps the reply path turns into admission/coalesce children.
+    # timestamps the reply path turns into admission/coalesce/staged
+    # children.
     span: Any = None
     t_drain: float = 0.0
     t_stage: float = 0.0
@@ -387,60 +391,23 @@ class QueryScheduler(OpenrEventBase):
             pass
 
     def _execute(self, batch: _Batch) -> None:
-        from ..device.engine import EpochMismatchError
-
+        tr = _trace.TRACE
+        traced: list = []
+        t_exec = 0.0
+        if tr is not None:
+            t_exec = time.perf_counter()  # ends each query's "staged"
+            traced = [p.span for p in batch.pendings if p.span is not None]
         if self.trace_hook is not None:
             self.trace_hook("execute_begin", batch)
         try:
-            per_query: Optional[list] = None
-            error: Optional[Exception] = None
-            # optimize_metrics never retries an epoch mismatch: a flap
-            # mid-descent means the whole run optimized a topology that
-            # no longer exists — the run aborts loudly (the caller sees
-            # EpochMismatchError) instead of silently re-pinning and
-            # publishing metrics tuned for the stale graph
-            attempts = (
-                1 if batch.op == "optimize_metrics" else _MAX_EPOCH_RETRIES
-            )
-            tr = _trace.TRACE
-            d_spans: list = []
-            if tr is not None:
-                # one open "dispatch" child per traced query in the batch;
-                # activating them all lets ONE engine-rung annotation land
-                # on every coalesced query's tree (fan-in scope)
-                d_spans = [
-                    tr.child_open(p.span, "dispatch")
-                    for p in batch.pendings
-                    if p.span is not None
-                ]
-            for _attempt in range(attempts):
-                try:
-                    if d_spans:
-                        with tr.activate(d_spans):
-                            per_query = self._run_batch(batch)
-                    else:
-                        per_query = self._run_batch(batch)
-                    error = None
-                    break
-                except EpochMismatchError as e:
-                    # a flap landed between coalescing and dispatch:
-                    # re-pin the fresh epoch and recompute — coalesced
-                    # work is invalidated, never served stale
-                    self._bump("serving.invalidations")
-                    if d_spans:
-                        with tr.activate(d_spans):
-                            tr.event("epoch_retry")
-                    batch.epoch = int(self.backend.epoch(batch.area))
-                    error = e
-                except Exception as e:  # noqa: BLE001
-                    log.debug(
-                        "serving: batch %s failed", batch.op, exc_info=True
-                    )
-                    error = e
-                    break
-            if d_spans:
-                for ds in d_spans:
-                    ds.finish()
+            if traced:
+                # one "dispatch" child per traced query in the batch, all
+                # active at once: ONE engine-rung annotation lands on
+                # every coalesced query's tree (fan-in scope)
+                with tr.fan_in(traced, "dispatch"):
+                    per_query, error = self._run_attempts(batch)
+            else:
+                per_query, error = self._run_attempts(batch)
             n = len(batch.pendings)
             with self._lock:
                 self.counters["serving.batches"] += 1
@@ -461,7 +428,7 @@ class QueryScheduler(OpenrEventBase):
                 self._hist.record_us(latency_us)
                 sp = pending.span
                 if sp is not None and tr is not None:
-                    self._trace_reply(tr, pending, t_done)
+                    self._trace_reply(tr, pending, t_exec)
                 if pending.future.done():
                     continue
                 self._bump("serving.replies")
@@ -471,18 +438,53 @@ class QueryScheduler(OpenrEventBase):
                         latency_us=latency_us,
                         batch_size=n,
                         epoch=batch.epoch,
+                        t_done=t_done,
                     )
                 )
         finally:
             if self.trace_hook is not None:
                 self.trace_hook("execute_end", batch)
 
+    def _run_attempts(
+        self, batch: _Batch
+    ) -> tuple[Optional[list], Optional[Exception]]:
+        """Run the batch, re-pinning the epoch a bounded number of times;
+        returns (per-query values, None) or (None, the last error)."""
+        from ..device.engine import EpochMismatchError
+
+        # optimize_metrics never retries an epoch mismatch: a flap
+        # mid-descent means the whole run optimized a topology that
+        # no longer exists — the run aborts loudly (the caller sees
+        # EpochMismatchError) instead of silently re-pinning and
+        # publishing metrics tuned for the stale graph
+        attempts = 1 if batch.op == "optimize_metrics" else _MAX_EPOCH_RETRIES
+        error: Optional[Exception] = None
+        for _attempt in range(attempts):
+            try:
+                return self._run_batch(batch), None
+            except EpochMismatchError as e:
+                # a flap landed between coalescing and dispatch:
+                # re-pin the fresh epoch and recompute — coalesced
+                # work is invalidated, never served stale
+                self._bump("serving.invalidations")
+                tr = _trace.TRACE
+                if tr is not None:
+                    tr.event("epoch_retry")
+                batch.epoch = int(self.backend.epoch(batch.area))
+                error = e
+            except Exception as e:  # noqa: BLE001
+                log.debug("serving: batch %s failed", batch.op, exc_info=True)
+                return None, e
+        return None, error
+
     @staticmethod
-    def _trace_reply(tr, pending: _Pending, t_done: float) -> None:
+    def _trace_reply(tr, pending: _Pending, t_exec: float) -> None:
         """Turn the recorded stage boundaries into completed children and
-        close out the query's trace: admission -> coalesce -> dispatch ->
-        reply (the dispatch child was opened live in _execute so engine
-        rung annotations landed on it)."""
+        close out the query's trace: admission -> coalesce -> staged
+        (waiting in the one-slot staging queue for the executor) ->
+        dispatch (opened live in _execute so engine rung annotations
+        landed on it).  The ctrl server's "ctrl.reply" root times the
+        reply from here on."""
         sp = pending.span
 
         def us(t: float) -> int:
@@ -492,7 +494,8 @@ class QueryScheduler(OpenrEventBase):
             tr.stage(sp, "admission", us(pending.t_submit), us(pending.t_drain))
             if pending.t_stage:
                 tr.stage(sp, "coalesce", us(pending.t_drain), us(pending.t_stage))
-        tr.stage(sp, "reply", us(t_done), us(t_done))
+                if t_exec:
+                    tr.stage(sp, "staged", us(pending.t_stage), us(t_exec))
         sp.tags["outcome"] = "ok"
         tr.finish_root(sp)
 

@@ -2,7 +2,8 @@
 
 Tests run on a virtual 8-device CPU mesh so multi-chip sharding paths are
 exercised without TPU hardware; the program runs on the chip through
-`chip_smoke.py`.  Env vars must be set before jax imports anywhere.
+`python -m perf.run` (BENCHMARK.json).  Env vars must be set before jax
+imports anywhere.
 """
 
 import os

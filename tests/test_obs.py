@@ -303,7 +303,7 @@ class TestServingSpanTree:
             assert tree["tags"]["outcome"] == "ok"
             assert tree["tags"]["op"] == "paths"
             stages = {c["name"]: c for c in tree["children"]}
-            assert {"admission", "coalesce", "dispatch", "reply"} <= set(
+            assert {"admission", "coalesce", "dispatch", "staged"} <= set(
                 stages
             )
             # the dispatch stage names the exact engine rung taken and

@@ -4,8 +4,8 @@ Nothing here runs: each test lowers a program for one chip of a
 described (not attached) `v5e:2x2` topology and compiles it with the
 TPU compiler installed here, which refuses what the chip would refuse —
 Mosaic lowerings, tile shapes, device memory.  Shapes are the fat-tree
-of BASELINE config #2 (10,080 switches, 95,232 directed adjacencies),
-the point `chip_smoke.py` drives on the chip.
+of BASELINE config #2 (10,080 switches, 95,232 directed adjacencies);
+on the chip, `python -m perf.run` runs the daemon path (BENCHMARK.json).
 
 The topology is described inside a fixture only (never at import, in
 conftest or in a parametrize/skipif): one process at a time may load
