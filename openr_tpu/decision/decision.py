@@ -6,6 +6,11 @@ publication and static-routes queues, per-key publication parsing
 ("adj:" / "prefix:" / "fibTime:"), pending-update batching with oldest-wins
 perf events, debounced full/incremental route rebuild, cold-start hold,
 RibPolicy application with TTL expiry, and ordered-FIB hold decrements.
+
+A rebuild is full (every route, then a diff of the whole table) when it
+is forced, else incremental: the nodes whose SPF entry or drain bit
+changed since the last build (RouteInputs.dirty_nodes) name the routes
+that can differ, and only those are recomputed and diffed.
 """
 
 from __future__ import annotations
@@ -36,13 +41,17 @@ from .link_state import LinkState, LinkStateChange
 from .prefix_state import PrefixState
 from .rib import DecisionRouteDb, DecisionRouteUpdate
 from .rib_policy import PolicyError, RibPolicy, RibPolicyConfig
-from .spf_solver import HostSpfBackend, SpfBackend, SpfSolver
+from .spf_solver import HostSpfBackend, RouteInputs, SpfBackend, SpfSolver
 
 FIB_TIME_MARKER = "fibTime:"
 
 # pre-seeded into Decision.counters so both wire surfaces expose them
 # from daemon start
-DECISION_COUNTER_KEYS = ("decision.rebuilds",)
+DECISION_COUNTER_KEYS = (
+    "decision.rebuilds",
+    "decision.incremental_rebuilds",
+    "decision.dirty_nodes",
+)
 
 
 class DecisionPendingUpdates:
@@ -53,11 +62,18 @@ class DecisionPendingUpdates:
         self.my_node_name = my_node_name
         self.count = 0
         self.perf_events: Optional[PerfEvents] = None
+        # forced: every route is rebuilt.  A remote topology change may
+        # take the incremental path.
         self.needs_full_rebuild = False
+        self.remote_topology_changed = False
         self.updated_prefixes: set[str] = set()
 
     def needs_route_update(self) -> bool:
-        return self.needs_full_rebuild or bool(self.updated_prefixes)
+        return (
+            self.needs_full_rebuild
+            or self.remote_topology_changed
+            or bool(self.updated_prefixes)
+        )
 
     def set_needs_full_rebuild(self) -> None:
         self.needs_full_rebuild = True
@@ -68,12 +84,16 @@ class DecisionPendingUpdates:
         change: LinkStateChange,
         perf_events: Optional[PerfEvents],
     ) -> None:
-        self.needs_full_rebuild |= (
-            change.topology_changed
-            or change.node_label_changed
+        if node_name == self.my_node_name:
             # link attribute changes only matter locally (nexthop/label)
-            or (change.link_attributes_changed and node_name == self.my_node_name)
-        )
+            self.needs_full_rebuild |= (
+                change.topology_changed
+                or change.node_label_changed
+                or change.link_attributes_changed
+            )
+        else:
+            self.needs_full_rebuild |= change.node_label_changed
+            self.remote_topology_changed |= change.topology_changed
         self._add_update(perf_events)
 
     def apply_prefix_state_change(
@@ -86,6 +106,7 @@ class DecisionPendingUpdates:
         self.count = 0
         self.perf_events = None
         self.needs_full_rebuild = False
+        self.remote_topology_changed = False
         self.updated_prefixes = set()
 
     def add_event(self, event: str) -> None:
@@ -155,6 +176,8 @@ class Decision(OpenrEventBase):
         self.prefix_state = PrefixState()
         self.pending_updates = DecisionPendingUpdates(my_node_name)
         self.route_db = DecisionRouteDb()
+        # what the last build read (None: the next build is full)
+        self._route_inputs: Optional[RouteInputs] = None
         self.rib_policy: Optional[RibPolicy] = None
         self._rib_policy_timeout = None
         self._fib_times: dict[str, float] = {}  # node -> fib time (s)
@@ -361,6 +384,7 @@ class Decision(OpenrEventBase):
                 normalize_prefix(p) for p in delta.unicast_routes_to_delete
             }
             self.pending_updates.apply_prefix_state_change(change, None)
+            self.pending_updates.set_needs_full_rebuild()
         if delta.mpls_routes_to_update or delta.mpls_routes_to_delete:
             self.spf_solver.update_static_mpls_routes(
                 [e.to_mpls_route() for e in delta.mpls_routes_to_update],
@@ -417,32 +441,52 @@ class Decision(OpenrEventBase):
         self._route_updates_queue.push(update)
 
     def _compute_route_update(self) -> DecisionRouteUpdate:
-        update = DecisionRouteUpdate()
-        if self.pending_updates.needs_full_rebuild:
-            with _trace.maybe_child("decision.route_build"):
-                maybe_db = self.spf_solver.build_route_db(
-                    self.area_link_states, self.prefix_state
+        """Full rebuild when forced, when there is no snapshot of the last
+        build, while a RIB policy has lapsed (its timer rebuilds in full)
+        or when the snapshots say a change can move every route; the
+        incremental rebuild of the dirty routes otherwise."""
+        solver = self.spf_solver
+        pending = self.pending_updates
+        prev, self._route_inputs = self._route_inputs, None
+        with _trace.maybe_child("decision.route_build"):
+            inputs = solver.route_inputs(self.area_link_states)
+            dirty = None
+            if (
+                prev is not None
+                and not pending.needs_full_rebuild
+                and (self.rib_policy is None or self.rib_policy.is_active())
+            ):
+                dirty = prev.dirty_nodes(inputs)
+            if dirty is None:
+                db = solver.build_route_db(self.area_link_states, self.prefix_state)
+                inputs.labels_exclusive = solver.node_labels_exclusive(
+                    self.area_link_states
                 )
-            db = maybe_db if maybe_db is not None else DecisionRouteDb()
+            else:
+                inputs.labels_exclusive = prev.labels_exclusive
+                unicast, mpls = solver.build_dirty_routes(
+                    self.area_link_states,
+                    self.prefix_state,
+                    pending.updated_prefixes,
+                    dirty,
+                )
+        if dirty is None:
+            if db is None:
+                db, inputs = DecisionRouteDb(), None
             if self.rib_policy is not None:
                 self.rib_policy.apply_policy(db.unicast_routes)
             with _trace.maybe_child("decision.route_diff"):
                 update = self.route_db.calculate_update(db)
         else:
-            with _trace.maybe_child("decision.route_build"):
-                for prefix in self.pending_updates.updated_prefixes:
-                    route = self.spf_solver.create_route_for_prefix_or_get_static_route(
-                        self.area_link_states, self.prefix_state, prefix
-                    )
-                    if route is not None:
-                        update.add_route_to_update(route)
-                    else:
-                        update.unicast_routes_to_delete.append(prefix)
+            self._bump("decision.incremental_rebuilds")
+            self._bump("decision.dirty_nodes", len(dirty))
             if self.rib_policy is not None:
-                changes = self.rib_policy.apply_policy(
-                    update.unicast_routes_to_update
+                self.rib_policy.apply_policy(
+                    {p: r for p, r in unicast.items() if r is not None}
                 )
-                update.unicast_routes_to_delete.extend(changes.deleted_routes)
+            with _trace.maybe_child("decision.route_diff"):
+                update = self.route_db.calculate_partial_update(unicast, mpls)
+        self._route_inputs = inputs
         return update
 
     # -- ordered-FIB holds ---------------------------------------------------

@@ -384,6 +384,9 @@ class LinkState:
         hv = self._node_overloads.get(node)
         return hv is not None and hv.value
 
+    def overloaded_nodes(self) -> frozenset[str]:
+        return frozenset(n for n, hv in self._node_overloads.items() if hv.value)
+
     @property
     def all_links(self) -> set[Link]:
         return self._all_links

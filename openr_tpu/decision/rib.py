@@ -129,21 +129,36 @@ class DecisionRouteDb:
     def calculate_update(self, new_db: "DecisionRouteDb") -> DecisionRouteUpdate:
         """Reference: DecisionRouteDb::calculateUpdate
         (openr/decision/Decision.cpp:111-147)."""
+        unicast: dict[str, Optional[RibUnicastEntry]] = dict.fromkeys(
+            self.unicast_routes
+        )
+        unicast.update(new_db.unicast_routes)
+        mpls: dict[int, Optional[RibMplsEntry]] = dict.fromkeys(self.mpls_routes)
+        mpls.update(new_db.mpls_routes)
+        return self.calculate_partial_update(unicast, mpls)
+
+    def calculate_partial_update(
+        self,
+        unicast: dict[str, Optional[RibUnicastEntry]],
+        mpls: dict[int, Optional[RibMplsEntry]],
+    ) -> DecisionRouteUpdate:
+        """calculate_update over the given prefixes and labels only, each
+        mapped to its new entry or to None where the new DB has none."""
         delta = DecisionRouteUpdate()
-        for prefix, entry in new_db.unicast_routes.items():
+        for prefix, entry in unicast.items():
             old = self.unicast_routes.get(prefix)
-            if old is None or old != entry:
+            if entry is None:
+                if old is not None:
+                    delta.unicast_routes_to_delete.append(prefix)
+            elif old is None or old != entry:
                 delta.add_route_to_update(entry)
-        for prefix in self.unicast_routes:
-            if prefix not in new_db.unicast_routes:
-                delta.unicast_routes_to_delete.append(prefix)
-        for label, entry in new_db.mpls_routes.items():
+        for label, entry in mpls.items():
             old = self.mpls_routes.get(label)
-            if old is None or old != entry:
+            if entry is None:
+                if old is not None:
+                    delta.mpls_routes_to_delete.append(label)
+            elif old is None or old != entry:
                 delta.mpls_routes_to_update.append(entry)
-        for label in self.mpls_routes:
-            if label not in new_db.mpls_routes:
-                delta.mpls_routes_to_delete.append(label)
         return delta
 
     def update(self, delta: DecisionRouteUpdate) -> None:

@@ -21,7 +21,7 @@ import ipaddress
 import logging
 import math
 import weakref
-from dataclasses import replace
+from dataclasses import dataclass, replace
 from typing import Optional, Protocol
 
 import numpy as np
@@ -101,6 +101,58 @@ class BestRouteSelectionResult:
 
     def has_node(self, node: str) -> bool:
         return any(n == node for n, _ in self.all_node_areas)
+
+
+@dataclass(slots=True)
+class RouteInputs:
+    """What the daemon's own route build reads of each area besides the
+    prefixes, the node labels and the static routes: its SPF result, the
+    drained nodes, the node set and its own links.  Two snapshots name
+    the nodes whose routes can differ between two builds
+    (`dirty_nodes`).  A label or static route change forces a full
+    build, so `labels_exclusive` is computed there and carried over."""
+
+    spf: dict[str, SpfResult]
+    overloaded: dict[str, frozenset[str]]
+    nodes: dict[str, frozenset[str]]
+    # (neighbour, iface, metric, nh v4, nh v6, up, adj label, weight)
+    own_links: dict[str, tuple]
+    # no node label is shared with another node, one of the daemon's
+    # adjacency labels or a static MPLS route: each label's route then
+    # depends on its own node alone
+    labels_exclusive: bool = False
+
+    def dirty_nodes(self, new: "RouteInputs") -> Optional[set[str]]:
+        """The nodes reachable in only one snapshot, or whose metric,
+        first hops or drain bit differ.  None where a change can move
+        every route: the node set, the daemon's own links or a
+        neighbour's entry (`spf[nh].metric` enters every next-hop test)
+        changed, or a node label is not exclusive."""
+        if not (
+            self.labels_exclusive
+            and self.nodes == new.nodes
+            and self.own_links == new.own_links
+        ):
+            return None
+        dirty: set[str] = set()
+        for area, spf in new.spf.items():
+            dirty |= self.overloaded[area] ^ new.overloaded[area]
+            old = self.spf[area]
+            if old is spf:
+                continue
+            dirty.update(old.keys() - spf.keys())
+            for node, res in spf.items():
+                prev = old.get(node)
+                if (
+                    prev is None
+                    or prev.metric != res.metric
+                    or prev.next_hops != res.next_hops
+                ):
+                    dirty.add(node)
+        for links in new.own_links.values():
+            if any(link[0] in dirty for link in links):
+                return None
+        return dirty
 
 
 class SpfBackend(Protocol):
@@ -589,11 +641,10 @@ class SpfSolver:
         """Reference: createRouteForPrefix (Decision.cpp:445-613)."""
         self._bump("decision.get_route_for_prefix")
         prefix = normalize_prefix(prefix)
+        self.best_routes_cache.pop(prefix, None)
         all_prefix_entries = prefix_state.prefixes.get(prefix)
         if not all_prefix_entries:
             return None
-
-        self.best_routes_cache.pop(prefix, None)
 
         # keep entries of reachable nodes only (per area)
         prefix_entries: PrefixEntries = dict(all_prefix_entries)
@@ -1374,31 +1425,7 @@ class SpfSolver:
         try:
             route_db = DecisionRouteDb()
             self.best_routes_cache.clear()
-
-            # batched KSP pre-pass: union every KSP2 prefix's advertising
-            # nodes (a superset of the best-route winners) and prefetch
-            # k=1/k=2 for all of them in ONE masked device run per area —
-            # the per-prefix loop then only hits the backend's cache.
-            # Without this, each prefix's miss dispatched its own masked
-            # kernel run (measured: 31 dispatches instead of 1 on the
-            # 32-prefix KSP2 bench).
-            prefetch = getattr(self.spf, "prefetch_kth_paths", None)
-            if prefetch is not None:
-                ksp2_dests: set[str] = set()
-                for entries in prefix_state.prefixes.values():
-                    for (node, _area), entry in entries.items():
-                        if (
-                            entry.forwarding_algorithm
-                            == PrefixForwardingAlgorithm.KSP2_ED_ECMP
-                            and node != me
-                        ):
-                            ksp2_dests.add(node)
-                if ksp2_dests:
-                    for link_state in area_link_states.values():
-                        try:
-                            prefetch(link_state, me, sorted(ksp2_dests))
-                        except Exception:
-                            self._bump("decision.device_fallbacks")
+            self._prefetch_ksp2_paths(area_link_states, prefix_state)
 
             for prefix in prefix_state.prefixes:
                 route = self.create_route_for_prefix(
@@ -1426,6 +1453,135 @@ class SpfSolver:
         finally:
             self.my_node_name = prev_me
             self._fleet_views = prev_fleet
+
+    def _prefetch_ksp2_paths(
+        self,
+        area_link_states: dict[str, LinkState],
+        prefix_state: PrefixState,
+    ) -> None:
+        """Batched KSP pre-pass: union every KSP2 prefix's advertising
+        nodes (a superset of the best-route winners) and prefetch k=1/k=2
+        for all of them in ONE masked device run per area — the
+        per-prefix loop then only hits the backend's cache.  Without
+        this, each prefix's miss dispatched its own masked kernel run
+        (measured: 31 dispatches instead of 1 on the 32-prefix KSP2
+        bench)."""
+        prefetch = getattr(self.spf, "prefetch_kth_paths", None)
+        if prefetch is None:
+            return
+        me = self.my_node_name
+        ksp2_dests: set[str] = set()
+        for prefix in prefix_state.ksp2_prefixes:
+            for (node, _area), entry in prefix_state.prefixes[prefix].items():
+                if (
+                    entry.forwarding_algorithm
+                    == PrefixForwardingAlgorithm.KSP2_ED_ECMP
+                    and node != me
+                ):
+                    ksp2_dests.add(node)
+        if not ksp2_dests:
+            return
+        for link_state in area_link_states.values():
+            try:
+                prefetch(link_state, me, sorted(ksp2_dests))
+            except Exception:
+                self._bump("decision.device_fallbacks")
+
+    # -- incremental route rebuild ---------------------------------------------
+
+    def route_inputs(self, area_link_states: dict[str, LinkState]) -> RouteInputs:
+        """Snapshot what the daemon's own build reads of each area
+        (RouteInputs), `labels_exclusive` left to the caller.  Decision's
+        own build runs on no fleet view: views are set only for the
+        length of a `build_route_db` call."""
+        me = self.my_node_name
+        spf: dict[str, SpfResult] = {}
+        overloaded: dict[str, frozenset[str]] = {}
+        nodes: dict[str, frozenset[str]] = {}
+        own_links: dict[str, tuple] = {}
+        for area, ls in area_link_states.items():
+            spf[area] = self._spf_result(ls, me)
+            overloaded[area] = ls.overloaded_nodes()
+            nodes[area] = frozenset(ls.get_adjacency_databases())
+            own_links[area] = tuple(
+                (
+                    link.other_node_name(me),
+                    link.iface_from_node(me),
+                    link.metric_from_node(me),
+                    link.nh_v4_from_node(me),
+                    link.nh_v6_from_node(me),
+                    link.is_up(),
+                    link.adj_label_from_node(me),
+                    link.weight_from_node(me),
+                )
+                for link in ls.ordered_links_from_node(me)
+            )
+        return RouteInputs(
+            spf=spf, overloaded=overloaded, nodes=nodes, own_links=own_links
+        )
+
+    def node_labels_exclusive(self, area_link_states: dict[str, LinkState]) -> bool:
+        """No node label is shared with another node, one of the daemon's
+        adjacency labels or a static MPLS route (RouteInputs)."""
+        me = self.my_node_name
+        node_labels: list[int] = []
+        adj_labels: set[int] = set()
+        for ls in area_link_states.values():
+            node_labels += (
+                db.node_label
+                for db in ls.get_adjacency_databases().values()
+                if db.node_label
+            )
+            adj_labels.update(
+                link.adj_label_from_node(me) for link in ls.ordered_links_from_node(me)
+            )
+        distinct = set(node_labels)
+        return (
+            len(distinct) == len(node_labels)
+            and distinct.isdisjoint(adj_labels)
+            and distinct.isdisjoint(self.static_mpls_routes)
+        )
+
+    def build_dirty_routes(
+        self,
+        area_link_states: dict[str, LinkState],
+        prefix_state: PrefixState,
+        prefixes: set[str],
+        nodes: set[str],
+    ) -> tuple[
+        dict[str, Optional[RibUnicastEntry]], dict[int, Optional[RibMplsEntry]]
+    ]:
+        """The daemon's routes that can differ from its last build, where
+        `nodes` are the dirty nodes between the two builds' RouteInputs
+        (the reference recomputes every route on a topology change,
+        Decision.cpp rebuildRoutes).  Recomputes `prefixes` (changed
+        advertisements), every prefix a dirty node advertises in any
+        area, every KSP2_ED_ECMP prefix (a second disjoint path can
+        change while no SPF entry does), and the node-label routes of
+        the dirty nodes.  Returns ({prefix: route}, {label: route}),
+        None where the build has no route."""
+        dirty = set(prefixes) | prefix_state.ksp2_prefixes
+        for node in nodes:
+            for area in area_link_states:
+                dirty |= prefix_state.prefixes_of(node, area)
+        self._prefetch_ksp2_paths(area_link_states, prefix_state)
+        unicast = {
+            prefix: self.create_route_for_prefix_or_get_static_route(
+                area_link_states, prefix_state, prefix
+            )
+            for prefix in dirty
+        }
+        mpls: dict[int, Optional[RibMplsEntry]] = {}
+        for area, link_state in area_link_states.items():
+            adj_dbs = link_state.get_adjacency_databases()
+            for node in nodes:
+                db = adj_dbs.get(node)
+                if db is None or not is_mpls_label_valid(db.node_label):
+                    continue
+                mpls[db.node_label] = self._node_label_route(
+                    node, area, db.node_label, area_link_states
+                )
+        return unicast, mpls
 
     def _build_fleet_views(
         self,
@@ -1606,43 +1762,50 @@ class SpfSolver:
                     # (Decision.cpp:679-689)
                     if existing[0] < node:
                         continue
-                if node == self.my_node_name:
-                    nh = NextHop(
-                        address="::",
-                        area=area,
-                        mpls_action=MplsAction(MplsActionCode.POP_AND_LOOKUP),
-                    )
-                    label_to_node[top_label] = (
-                        node,
-                        RibMplsEntry(top_label, frozenset({nh})),
-                    )
-                    continue
-                min_metric, nexthop_nodes = self._get_next_hops_with_metric(
-                    {(node, area)}, False, area_link_states
+                entry = self._node_label_route(
+                    node, area, top_label, area_link_states
                 )
-                if not nexthop_nodes:
-                    self._bump("decision.no_route_to_label")
-                    continue
-                label_to_node[top_label] = (
-                    node,
-                    RibMplsEntry(
-                        top_label,
-                        frozenset(
-                            self._get_next_hops(
-                                {(node, area)},
-                                False,
-                                False,
-                                min_metric,
-                                nexthop_nodes,
-                                top_label,
-                                area_link_states,
-                                {},
-                            )
-                        ),
-                    ),
-                )
+                if entry is not None:
+                    label_to_node[top_label] = (node, entry)
         for _label, (_node, entry) in label_to_node.items():
             route_db.add_mpls_route(entry)
+
+    def _node_label_route(
+        self,
+        node: str,
+        area: str,
+        top_label: int,
+        area_link_states: dict[str, LinkState],
+    ) -> Optional[RibMplsEntry]:
+        """One node label's MPLS route (Decision.cpp:690-745)."""
+        if node == self.my_node_name:
+            nh = NextHop(
+                address="::",
+                area=area,
+                mpls_action=MplsAction(MplsActionCode.POP_AND_LOOKUP),
+            )
+            return RibMplsEntry(top_label, frozenset({nh}))
+        min_metric, nexthop_nodes = self._get_next_hops_with_metric(
+            {(node, area)}, False, area_link_states
+        )
+        if not nexthop_nodes:
+            self._bump("decision.no_route_to_label")
+            return None
+        return RibMplsEntry(
+            top_label,
+            frozenset(
+                self._get_next_hops(
+                    {(node, area)},
+                    False,
+                    False,
+                    min_metric,
+                    nexthop_nodes,
+                    top_label,
+                    area_link_states,
+                    {},
+                )
+            ),
+        )
 
     def _build_adj_label_routes(
         self,

@@ -485,6 +485,48 @@ class TestCounterRegistrySweep:
             shim.wait_until_stopped(5)
         assert set(ENGINE_COUNTER_KEYS) <= set(shimmed)
 
+    def test_decision_rebuild_family_on_both_wire_surfaces(self, daemon):
+        """Decision pre-seeds its rebuild counters, so how often the
+        incremental route rebuild engages (decision.incremental_rebuilds
+        against decision.rebuilds, decision.dirty_nodes summed) answers
+        ONE getCounters on the native ctrl server AND the fb303 shim
+        before any route rebuild runs."""
+        from openr_tpu.decision.decision import DECISION_COUNTER_KEYS
+        from openr_tpu.interop import thrift_binary as tb
+        from openr_tpu.interop.shim import ThriftBinaryShim
+        from test_thrift_binary import _call_ok
+
+        family = {"decision.incremental_rebuilds", "decision.dirty_nodes"}
+        assert family <= set(DECISION_COUNTER_KEYS)
+
+        client = CtrlClient(port=daemon.ctrl_port)
+        try:
+            native = client.call("getCounters")
+        finally:
+            client.close()
+        assert family <= set(native)
+
+        shim = ThriftBinaryShim(
+            daemon.kvstore,
+            port=0,
+            node_name="solo",
+            counters_fn=daemon.ctrl_server.handler._all_counters,
+        )
+        shim.run()
+        try:
+            shimmed = _call_ok(
+                shim.port,
+                "getCounters",
+                44,
+                b"\x00",
+                ("map", tb.T_STRING, tb.T_I64),
+                dec=lambda m: {k.decode(): v for k, v in m.items()},
+            )
+        finally:
+            shim.stop()
+            shim.wait_until_stopped(5)
+        assert family <= set(shimmed)
+
     def test_pallas_family_on_both_wire_surfaces(self, daemon):
         """The Pallas kernel ledger (launches per kind, demotions,
         policy skips) is pre-seeded in the engine registry, so the
