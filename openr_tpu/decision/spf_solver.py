@@ -52,6 +52,11 @@ from .rib import DecisionRouteDb, RibMplsEntry, RibUnicastEntry
 
 log = logging.getLogger(__name__)
 
+# the batched KSP2 pre-pass (DeviceSpfBackend.prefetch_kth_paths):
+# masked device rows run, and k=1 plus k=2 paths traced; pre-seeded into
+# SpfSolver.counters like the delta family
+KSP2_COUNTER_KEYS = ("decision.ksp2_rows", "decision.ksp2_paths")
+
 MPLS_LABEL_MIN = 16
 MPLS_LABEL_MAX = (1 << 20) - 1
 
@@ -459,7 +464,7 @@ class DeviceSpfBackend:
 
     def prefetch_kth_paths(
         self, link_state: LinkState, src: str, dests: list[str]
-    ) -> None:
+    ) -> tuple[int, int]:
         """k=1 and k=2 edge-disjoint paths for many destinations in ONE
         masked device run.
 
@@ -468,57 +473,76 @@ class DeviceSpfBackend:
         (LinkState.cpp:763-793).  The exclusion sets differ per
         destination, which is exactly the kernel's per-row mask axis
         (ops.sssp.spf_forward_ell_masked): row d = SPF from src with
-        dest-d's first-path links down."""
-        from .link_state import trace_one_path
+        dest-d's first-path links down.
 
+        Returns (masked rows run, paths traced), which the solver counts
+        as decision.ksp2_rows and decision.ksp2_paths."""
         if not self._device_worthwhile(link_state, len(dests)):
-            return  # host recursion serves the per-prefix queries
+            return 0, 0  # host recursion serves the per-prefix queries
         csr = self._mirror(link_state)
         if src not in csr.node_id:
-            return  # unknown/linkless source: host fallback serves it
+            return 0, 0  # unknown/linkless source: host fallback serves it
         cache = self._kth_cache(link_state)
-        base = self.get_spf_result(link_state, src)
+        todo = [
+            d for d in dests if (src, d, 1) not in cache or (src, d, 2) not in cache
+        ]
+        if not todo:
+            return 0, 0
+        with _trace.maybe_child("decision.ksp2"):
+            return self._compute_kth_paths(link_state, csr, cache, src, todo)
 
+    def _compute_kth_paths(
+        self, link_state: LinkState, csr, cache: dict, src: str, dests: list[str]
+    ) -> tuple[int, int]:
+        from .link_state import trace_one_path
+
+        base = self.get_spf_result(link_state, src)
+        n_paths = 0
         # k=1: trace from the (cached, device-computed) base SP-DAG
         need_second: list[tuple[str, set]] = []
-        for dest in dests:
-            if (src, dest, 1) not in cache:
+        with _trace.maybe_child("ksp2.trace"):
+            for dest in dests:
+                if (src, dest, 1) not in cache:
+                    paths = []
+                    if dest in base:
+                        visited: set = set()
+                        # empty path (src == dest) is falsy and not
+                        # collected, matching LinkState.get_kth_paths
+                        while p := trace_one_path(src, dest, base, visited):
+                            paths.append(p)
+                    cache[(src, dest, 1)] = paths
+                    n_paths += len(paths)
+                if (src, dest, 2) not in cache:
+                    ignore = {
+                        link for path in cache[(src, dest, 1)] for link in path
+                    }
+                    if ignore:
+                        need_second.append((dest, ignore))
+                    else:
+                        cache[(src, dest, 2)] = []
+            if not need_second:
+                return 0, n_paths
+            link_edges = csr.edges_of_links()
+            mask = np.ones((len(need_second), csr.edge_capacity), dtype=bool)
+            for row, (_dest, ignore) in enumerate(need_second):
+                for link in ignore:
+                    for e in link_edges.get(link, ()):
+                        mask[row, e] = False
+        with _trace.maybe_child("ksp2.relax"):
+            dist, dag = csr.run_batched_spf(
+                [src] * len(need_second), extra_edge_mask=mask
+            )
+        with _trace.maybe_child("ksp2.decode"):
+            for row, (dest, _ignore) in enumerate(need_second):
+                res = csr.row_path_links(dist[row], dag[row])
                 paths = []
-                if dest in base:
-                    visited: set = set()
-                    # empty path (src == dest) is falsy and not collected,
-                    # matching LinkState.get_kth_paths
-                    while p := trace_one_path(src, dest, base, visited):
+                if dest in res:
+                    visited = set()
+                    while p := trace_one_path(src, dest, res, visited):
                         paths.append(p)
-                cache[(src, dest, 1)] = paths
-            if (src, dest, 2) not in cache:
-                ignore = {
-                    link for path in cache[(src, dest, 1)] for link in path
-                }
-                if ignore:
-                    need_second.append((dest, ignore))
-                else:
-                    cache[(src, dest, 2)] = []
-
-        if not need_second:
-            return
-        link_edges = csr.edges_of_links()
-        mask = np.ones((len(need_second), csr.edge_capacity), dtype=bool)
-        for row, (_dest, ignore) in enumerate(need_second):
-            for link in ignore:
-                for e in link_edges.get(link, ()):
-                    mask[row, e] = False
-        dist, dag = csr.run_batched_spf(
-            [src] * len(need_second), extra_edge_mask=mask
-        )
-        for row, (dest, _ignore) in enumerate(need_second):
-            res = csr.row_path_links(dist[row], dag[row])
-            paths = []
-            if dest in res:
-                visited = set()
-                while p := trace_one_path(src, dest, res, visited):
-                    paths.append(p)
-            cache[(src, dest, 2)] = paths
+                cache[(src, dest, 2)] = paths
+                n_paths += len(paths)
+        return len(need_second), n_paths
 
 
 class SpfSolver:
@@ -557,7 +581,9 @@ class SpfSolver:
         self.best_routes_cache: dict[str, BestRouteSelectionResult] = {}
         # the decision.delta.* family is pre-seeded so both wire surfaces
         # expose it from daemon start even before the rung ever engages
-        self.counters: dict[str, int] = {k: 0 for k in DELTA_COUNTER_KEYS}
+        self.counters: dict[str, int] = {
+            k: 0 for k in DELTA_COUNTER_KEYS + KSP2_COUNTER_KEYS
+        }
 
     def _bump(self, counter: str, n: int = 1) -> None:
         self.counters[counter] = self.counters.get(counter, 0) + n
@@ -1030,18 +1056,9 @@ class SpfSolver:
         for area, link_state in area_link_states.items():
             # batched device prefetch of k=1/k=2 for every best node (one
             # masked kernel run instead of per-destination host recursion)
-            prefetch = getattr(self.spf, "prefetch_kth_paths", None)
-            if prefetch is not None:
-                try:
-                    prefetch(
-                        link_state,
-                        self.my_node_name,
-                        sorted({node for node, _ in best.all_node_areas}),
-                    )
-                except Exception:
-                    # prefetch is an optimization: per-path queries below
-                    # fall back to the host oracle individually
-                    self._bump("decision.device_fallbacks")
+            self._prefetch_kth_paths(
+                link_state, sorted({node for node, _ in best.all_node_areas})
+            )
             # shortest paths first
             for node, best_area in sorted(best.all_node_areas):
                 if node == self.my_node_name and best_area == area:
@@ -1466,9 +1483,6 @@ class SpfSolver:
         this, each prefix's miss dispatched its own masked kernel run
         (measured: 31 dispatches instead of 1 on the 32-prefix KSP2
         bench)."""
-        prefetch = getattr(self.spf, "prefetch_kth_paths", None)
-        if prefetch is None:
-            return
         me = self.my_node_name
         ksp2_dests: set[str] = set()
         for prefix in prefix_state.ksp2_prefixes:
@@ -1482,10 +1496,22 @@ class SpfSolver:
         if not ksp2_dests:
             return
         for link_state in area_link_states.values():
-            try:
-                prefetch(link_state, me, sorted(ksp2_dests))
-            except Exception:
-                self._bump("decision.device_fallbacks")
+            self._prefetch_kth_paths(link_state, sorted(ksp2_dests))
+
+    def _prefetch_kth_paths(self, link_state: LinkState, dests: list[str]) -> None:
+        """The backend's batched k=1/k=2 prefetch, its masked rows and
+        traced paths counted.  Prefetch is an optimization: on a failure
+        the per-path queries fall back to the host oracle one by one."""
+        prefetch = getattr(self.spf, "prefetch_kth_paths", None)
+        if prefetch is None:
+            return
+        try:
+            rows, paths = prefetch(link_state, self.my_node_name, dests)
+        except Exception:
+            self._bump("decision.device_fallbacks")
+            return
+        self._bump("decision.ksp2_rows", rows)
+        self._bump("decision.ksp2_paths", paths)
 
     # -- incremental route rebuild ---------------------------------------------
 
