@@ -144,6 +144,8 @@ class CsrTopology:
     # the topology has band structure; drives the banded relax kernel
     banded: object = None
     _runner: object = None
+    # (key, order, start) of in_edges(); a full rebuild resets it to None
+    _in_edges: Optional[tuple] = None
 
     @property
     def runner(self):
@@ -647,23 +649,25 @@ class CsrTopology:
                 key=lambda lp: (result[lp[1]].metric, lp[1], lp[0])
             )
 
-    def row_path_links(self, dist_row: np.ndarray, dag_row: np.ndarray) -> SpfResult:
-        """One kernel row -> SpfResult with metric + path_links only (no
-        first-hop sets) — the shape `trace_one_path` walks for KSP path
-        extraction."""
-        from ..ops.sssp import INF32
+    def in_edges(self) -> tuple[np.ndarray, np.ndarray]:
+        """(order, start): the live edge ids sorted by destination, and
+        for each node id i its in-edges at order[start[i]:start[i+1]].
 
-        inf = int(INF32)
-        result: SpfResult = {}
-        for i in range(self.n_nodes):
-            if dist_row[i] < inf:
-                result[self.node_names[i]] = NodeSpfResult(int(dist_row[i]))
-        for e in np.nonzero(dag_row[: self.n_edges])[0]:
-            link, from_name = self.edge_links[e]
-            v = self.node_names[int(self.edge_dst[e])]
-            result[v].path_links.append((link, from_name))
-        self._host_order_path_links(result)
-        return result
+        Built once per edge-array state.  An in-place rewire recycles
+        retired slots and appends new edges, so the arrays are not kept
+        sorted by destination; it bumps rewire_seq (and n_edges when it
+        appends), which keys the rebuild.  Attribute refreshes leave the
+        index as it is, and a full rebuild resets it."""
+        key = (self.rewire_seq, self.n_edges)
+        if self._in_edges is None or self._in_edges[0] != key:
+            # retired slots (edge_links[e] is None) are not live
+            ids = np.flatnonzero(self.edge_live[: self.n_edges])
+            order = ids[np.argsort(self.edge_dst[ids], kind="stable")]
+            start = np.searchsorted(
+                self.edge_dst[order], np.arange(self.n_nodes + 1)
+            )
+            self._in_edges = (key, order, start)
+        return self._in_edges[1], self._in_edges[2]
 
     def edges_of_links(self) -> dict:
         """Link -> [directed edge ids] (both directions; parallel links map
@@ -783,3 +787,59 @@ class CsrTopology:
             link, from_name = lp
             deg.setdefault(from_name, set()).add(link.other_node_name(from_name))
         return max((len(v) for v in deg.values()), default=0)
+
+
+class RowPathView:
+    """One (dist, dag) kernel row read as the SpfResult `trace_one_path`
+    walks for KSP path extraction: metric and path_links, no first-hop
+    sets.
+
+    `name in view` is reachability; `view[name]` decodes that node's
+    NodeSpfResult on first access and keeps it for the row.  Its
+    path_links are the node's DAG in-edges in the order
+    CsrTopology._host_order_path_links gives, (dist(prev), prev name,
+    link): a total order, so `trace_one_path` takes the walk it takes on
+    the host Dijkstra's result.  A k=2 trace reads the nodes on its way
+    back from one destination, not the row's every node."""
+
+    __slots__ = ("_csr", "_dist", "_dag", "_inf", "_nodes")
+
+    def __init__(self, csr: CsrTopology, dist_row: np.ndarray, dag_row: np.ndarray):
+        from ..ops.sssp import INF32
+
+        self._csr = csr
+        self._dist = dist_row
+        self._dag = dag_row
+        self._inf = int(INF32)
+        self._nodes: dict[str, NodeSpfResult] = {}
+
+    @property
+    def decoded(self) -> int:
+        """Nodes decoded so far."""
+        return len(self._nodes)
+
+    def _dist_of(self, name: str) -> Optional[int]:
+        i = self._csr.node_id.get(name)
+        if i is None:
+            return None
+        d = int(self._dist[i])
+        return d if d < self._inf else None
+
+    def __contains__(self, name: str) -> bool:
+        return self._dist_of(name) is not None
+
+    def __getitem__(self, name: str) -> NodeSpfResult:
+        res = self._nodes.get(name)
+        if res is not None:
+            return res
+        metric = self._dist_of(name)
+        if metric is None:
+            raise KeyError(name)
+        csr = self._csr
+        order, start = csr.in_edges()
+        i = csr.node_id[name]
+        eids = order[start[i] : start[i + 1]]
+        links = [csr.edge_links[e] for e in eids[self._dag[eids]].tolist()]
+        links.sort(key=lambda lp: (self._dist_of(lp[1]), lp[1], lp[0]))
+        res = self._nodes[name] = NodeSpfResult(metric, links)
+        return res

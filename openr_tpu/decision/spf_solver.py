@@ -53,9 +53,14 @@ from .rib import DecisionRouteDb, RibMplsEntry, RibUnicastEntry
 log = logging.getLogger(__name__)
 
 # the batched KSP2 pre-pass (DeviceSpfBackend.prefetch_kth_paths):
-# masked device rows run, and k=1 plus k=2 paths traced; pre-seeded into
-# SpfSolver.counters like the delta family
-KSP2_COUNTER_KEYS = ("decision.ksp2_rows", "decision.ksp2_paths")
+# masked device rows run, k=1 plus k=2 paths traced, and the nodes the
+# k=2 traces decoded from those rows; pre-seeded into SpfSolver.counters
+# like the delta family
+KSP2_COUNTER_KEYS = (
+    "decision.ksp2_rows",
+    "decision.ksp2_paths",
+    "decision.ksp2_decoded_nodes",
+)
 
 MPLS_LABEL_MIN = 16
 MPLS_LABEL_MAX = (1 << 20) - 1
@@ -475,25 +480,27 @@ class DeviceSpfBackend:
         (ops.sssp.spf_forward_ell_masked): row d = SPF from src with
         dest-d's first-path links down.
 
-        Returns (masked rows run, paths traced), which the solver counts
-        as decision.ksp2_rows and decision.ksp2_paths."""
+        Returns (masked rows run, paths traced, nodes decoded from the
+        rows), which the solver counts as decision.ksp2_rows,
+        decision.ksp2_paths and decision.ksp2_decoded_nodes."""
         if not self._device_worthwhile(link_state, len(dests)):
-            return 0, 0  # host recursion serves the per-prefix queries
+            return 0, 0, 0  # host recursion serves the per-prefix queries
         csr = self._mirror(link_state)
         if src not in csr.node_id:
-            return 0, 0  # unknown/linkless source: host fallback serves it
+            return 0, 0, 0  # unknown/linkless source: host fallback serves it
         cache = self._kth_cache(link_state)
         todo = [
             d for d in dests if (src, d, 1) not in cache or (src, d, 2) not in cache
         ]
         if not todo:
-            return 0, 0
+            return 0, 0, 0
         with _trace.maybe_child("decision.ksp2"):
             return self._compute_kth_paths(link_state, csr, cache, src, todo)
 
     def _compute_kth_paths(
         self, link_state: LinkState, csr, cache: dict, src: str, dests: list[str]
-    ) -> tuple[int, int]:
+    ) -> tuple[int, int, int]:
+        from .csr import RowPathView
         from .link_state import trace_one_path
 
         base = self.get_spf_result(link_state, src)
@@ -521,7 +528,7 @@ class DeviceSpfBackend:
                     else:
                         cache[(src, dest, 2)] = []
             if not need_second:
-                return 0, n_paths
+                return 0, n_paths, 0
             link_edges = csr.edges_of_links()
             mask = np.ones((len(need_second), csr.edge_capacity), dtype=bool)
             for row, (_dest, ignore) in enumerate(need_second):
@@ -532,9 +539,10 @@ class DeviceSpfBackend:
             dist, dag = csr.run_batched_spf(
                 [src] * len(need_second), extra_edge_mask=mask
             )
+        decoded = 0
         with _trace.maybe_child("ksp2.decode"):
             for row, (dest, _ignore) in enumerate(need_second):
-                res = csr.row_path_links(dist[row], dag[row])
+                res = RowPathView(csr, dist[row], dag[row])
                 paths = []
                 if dest in res:
                     visited = set()
@@ -542,7 +550,8 @@ class DeviceSpfBackend:
                         paths.append(p)
                 cache[(src, dest, 2)] = paths
                 n_paths += len(paths)
-        return len(need_second), n_paths
+                decoded += res.decoded
+        return len(need_second), n_paths, decoded
 
 
 class SpfSolver:
@@ -1499,19 +1508,21 @@ class SpfSolver:
             self._prefetch_kth_paths(link_state, sorted(ksp2_dests))
 
     def _prefetch_kth_paths(self, link_state: LinkState, dests: list[str]) -> None:
-        """The backend's batched k=1/k=2 prefetch, its masked rows and
-        traced paths counted.  Prefetch is an optimization: on a failure
-        the per-path queries fall back to the host oracle one by one."""
+        """The backend's batched k=1/k=2 prefetch, its masked rows,
+        traced paths and decoded nodes counted.  Prefetch is an
+        optimization: on a failure the per-path queries fall back to the
+        host oracle one by one."""
         prefetch = getattr(self.spf, "prefetch_kth_paths", None)
         if prefetch is None:
             return
         try:
-            rows, paths = prefetch(link_state, self.my_node_name, dests)
+            rows, paths, decoded = prefetch(link_state, self.my_node_name, dests)
         except Exception:
             self._bump("decision.device_fallbacks")
             return
         self._bump("decision.ksp2_rows", rows)
         self._bump("decision.ksp2_paths", paths)
+        self._bump("decision.ksp2_decoded_nodes", decoded)
 
     # -- incremental route rebuild ---------------------------------------------
 

@@ -6,12 +6,15 @@ backends."""
 
 from __future__ import annotations
 
+import copy
+
 import pytest
 
 from openr_tpu.decision.link_state import LinkState
 from openr_tpu.decision.prefix_state import PrefixState
 from openr_tpu.decision.spf_solver import DeviceSpfBackend, SpfSolver
 from openr_tpu.types import (
+    Adjacency,
     PrefixEntry,
     PrefixForwardingAlgorithm,
     PrefixForwardingType,
@@ -90,6 +93,103 @@ class TestKthPathsConformance:
         after = backend.get_kth_paths(ls, "node-0-0", "node-3-3", 1)
         host = ls.get_kth_paths("node-0-0", "node-3-3", 1)
         assert canon(after) == canon(host)
+
+
+def _topology(name):
+    if name == "grid":
+        return grid_topology(8), "node-3-3"
+    seed = int(name.removeprefix("random-"))
+    dbs = random_topology(n_nodes=80, n_extra_edges=120, seed=seed)
+    return dbs, "n0"
+
+
+def _assert_raw_lists_equal(backend, ls_dev, ls_host, src, label):
+    """Every destination's k=1 and k=2 paths, list for list and in
+    order: the device decode promises the host Dijkstra's walk, not only
+    the same set of paths."""
+    dests = [d for d in sorted(ls_host.node_names) if d != src]
+    backend.prefetch_kth_paths(ls_dev, src, dests)
+    for dest in dests:
+        for k in (1, 2):
+            host = ls_host.get_kth_paths(src, dest, k)
+            dev = backend.get_kth_paths(ls_dev, src, dest, k)
+            assert dev == host, (label, dest, k)
+
+
+class TestKthPathsRawOrder:
+    @pytest.mark.parametrize(
+        "topology", ["random-0", "random-1", "random-2", "random-3", "grid"]
+    )
+    def test_raw_lists_equal_host(self, topology):
+        dbs, src = _topology(topology)
+        backend = DeviceSpfBackend(min_device_nodes=1, min_device_sources=1)
+        _assert_raw_lists_equal(backend, build_ls(dbs), build_ls(dbs), src, topology)
+
+    @pytest.mark.parametrize("topology", ["random-0", "grid"])
+    def test_raw_lists_equal_host_after_rewires(self, topology):
+        """In-place rewires on one mirror: a link removed, a link added
+        into the freed slots, a metric changed.  The in-edge index is
+        rebuilt when the edge arrays change and reused when they do
+        not, and the paths stay the host's after each step."""
+        dbs, src = _topology(topology)
+        by_name = {db.this_node_name: db for db in dbs}
+        ls_dev, ls_host = build_ls(dbs), build_ls(dbs)
+        backend = DeviceSpfBackend(min_device_nodes=1, min_device_sources=1)
+        _assert_raw_lists_equal(backend, ls_dev, ls_host, src, "start")
+        csr = backend._mirror(ls_dev)
+        index = csr._in_edges
+
+        def publish(*names):
+            for name in names:
+                for ls in (ls_dev, ls_host):
+                    ls.update_adjacency_database(copy.deepcopy(by_name[name]))
+
+        def adjacent(a, b):
+            return any(adj.other_node_name == b for adj in by_name[a].adjacencies)
+
+        # remove a link of the first path to the farthest destination
+        far = sorted(ls_host.node_names)[-1]
+        gone = ls_host.get_kth_paths(src, far, 1)[0][0]
+        a, b = gone.n1, gone.n2
+        for x, y in ((a, b), (b, a)):
+            by_name[x].adjacencies = [
+                adj for adj in by_name[x].adjacencies if adj.other_node_name != y
+            ]
+        publish(a, b)
+        _assert_raw_lists_equal(backend, ls_dev, ls_host, src, "removed")
+        assert backend._mirror(ls_dev) is csr and csr.rewire_seq == 1
+        assert csr._in_edges is not index
+        n_edges, index = csr.n_edges, csr._in_edges
+
+        # add a link from one end of the removed one to another node it
+        # does not reach directly: the two freed slots take it
+        c = next(
+            n for n in sorted(by_name, reverse=True)
+            if n not in (a, b) and not adjacent(a, n)
+        )
+        for x, y in ((a, c), (c, a)):
+            by_name[x].adjacencies.append(
+                Adjacency(
+                    other_node_name=y,
+                    if_name=f"if_{x}_{y}",
+                    other_if_name=f"if_{y}_{x}",
+                    metric=2,
+                    next_hop_v6=f"fe80::{len(x)}:{len(y)}",
+                )
+            )
+        publish(a, c)
+        _assert_raw_lists_equal(backend, ls_dev, ls_host, src, "added")
+        assert backend._mirror(ls_dev) is csr and csr.rewire_seq == 2
+        assert csr.n_edges == n_edges and csr._free_slots == []
+        assert csr._in_edges is not index
+        index = csr._in_edges
+
+        # a metric change moves no edge: the index is kept
+        by_name[src].adjacencies[0].metric += 3
+        publish(src)
+        _assert_raw_lists_equal(backend, ls_dev, ls_host, src, "metric")
+        assert backend._mirror(ls_dev) is csr and csr.rewire_seq == 2
+        assert csr._in_edges is index
 
 
 class TestKsp2RouteParity:

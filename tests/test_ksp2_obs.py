@@ -1,8 +1,10 @@
 """The KSP2 pre-pass's spans and counters: `decision.ksp2` with its
 children `ksp2.trace`, `ksp2.relax` and `ksp2.decode` once per route
-build that computes paths; `decision.ksp2_rows` (masked rows run) and
-`decision.ksp2_paths` (k=1 plus k=2 paths traced), pre-seeded so that
-both wire surfaces list them; and the route build unchanged by them."""
+build that computes paths; `decision.ksp2_rows` (masked rows run),
+`decision.ksp2_paths` (k=1 plus k=2 paths traced) and
+`decision.ksp2_decoded_nodes` (the nodes the k=2 traces decoded from the
+rows), pre-seeded so that both wire surfaces list them; and the route
+build unchanged by them."""
 
 from __future__ import annotations
 
@@ -138,6 +140,21 @@ def test_ksp2_counters_count_rows_and_paths():
     )
 
 
+def test_ksp2_decoded_nodes_counts_the_lazy_walk():
+    """Each masked row decodes only the nodes its k=2 traces read: at
+    least one a traced path, at most every node of every row."""
+    _dbs, ls, ps = ksp2_grid(8)
+    solver = _solver()
+    assert solver.counters["decision.ksp2_decoded_nodes"] == 0
+    solver.build_route_db({"0": ls}, ps)
+    dests = [n for n in ls.node_names if n != ME]
+    second = sum(len(ls.get_kth_paths(ME, d, 2)) for d in dests)
+    rows = solver.counters["decision.ksp2_rows"]
+    decoded = solver.counters["decision.ksp2_decoded_nodes"]
+    assert rows > 0 and second > 0
+    assert second <= decoded <= rows * len(ls.node_names)
+
+
 def test_ksp2_counters_on_both_wire_surfaces():
     """Pre-seeded: one getCounters on the native ctrl server and on the
     fb303 shim lists both before any KSP2 route is built."""
@@ -150,7 +167,11 @@ def test_ksp2_counters_on_both_wire_surfaces():
     from test_system import make_config
     from test_thrift_binary import _call_ok
 
-    family = {"decision.ksp2_rows", "decision.ksp2_paths"}
+    family = {
+        "decision.ksp2_rows",
+        "decision.ksp2_paths",
+        "decision.ksp2_decoded_nodes",
+    }
     daemon = OpenrDaemon(
         make_config("solo", ctrl_port=0),
         io_provider=MockIoProvider().endpoint("solo"),
@@ -184,8 +205,8 @@ def test_ksp2_counters_on_both_wire_surfaces():
             shim.wait_until_stopped(5)
     finally:
         daemon.stop()
-    assert family <= set(native)
-    assert family <= set(shimmed)
+    assert {k: native[k] for k in family} == dict.fromkeys(family, 0)
+    assert {k: shimmed[k] for k in family} == dict.fromkeys(family, 0)
 
 
 def test_route_db_unchanged_with_tracing_off():
