@@ -24,8 +24,7 @@ single-core host can produce:
 
 What this deliberately does NOT claim: real multi-chip wall-clock.  One
 core cannot time 8 devices; the artifact records the measured per-device
-cost division + overhead factor instead of asserting wall-time speedup
-(bench_details carries both numbers and this note).
+cost division + overhead factor instead of asserting wall-time speedup.
 """
 
 from __future__ import annotations
@@ -34,7 +33,7 @@ import json
 import os
 import time
 
-# wall budget shared with bench.py's rows (0 = uncapped); the blocked
+# wall budget (0 = uncapped); the blocked
 # 1M-node section is the sacrificial row when the budget runs short
 _BUDGET_S = float(os.environ.get("OPENR_BENCH_BUDGET_S", "0"))
 _START = time.monotonic()

@@ -446,46 +446,6 @@ def _drive_blocked(state: dict) -> None:
     )
 
 
-def _drive_pallas(state: dict) -> None:
-    """Pallas kernel rung (ops.pallas_kernels): run both hand-tiled
-    kernels in interpreter mode so their jit roots record specs — the
-    fused verify+bitmap epilogue through the fleet product, and the
-    blocked rank-B outer update through a 1-device blocked closure.
-    The mode is pinned on the engine instead of env-forcing
-    OPENR_PALLAS (the _drive_blocked discipline: no environment leaks
-    into other drivers); the counter asserts keep the driver honest —
-    a silent demotion would leave the pallas roots spec-less and fail
-    the audit later with a much less actionable finding."""
-    import jax
-
-    from ..decision.fleet import FleetViewCache
-    from ..device.engine import DeviceResidencyEngine
-    from ..parallel.blocked import make_blocked_mesh
-
-    ls = _ring_link_state()
-    engine = DeviceResidencyEngine()
-    engine.pallas_mode = "interpret"
-    view = FleetViewCache().view(
-        ls, ["r000", "r031", "r063"], engine=engine
-    )
-    assert view is not None and view.converged
-    c = engine.get_counters()
-    assert c["device.engine.pallas_products"] == 1
-    assert c["device.engine.pallas_fallbacks"] == 0
-
-    engine2 = DeviceResidencyEngine()
-    engine2.pallas_mode = "interpret"
-    engine2.blocked.node_shard_threshold = 0
-    engine2.blocked._mesh = make_blocked_mesh(jax.devices()[:1])
-    view2 = FleetViewCache().view(
-        ls, ["r000", "r031", "r063"], engine=engine2
-    )
-    assert view2 is not None and view2.converged and view2.node_sharded
-    c2 = engine2.get_counters()
-    assert c2["device.engine.pallas_outer_updates"] > 0
-    assert c2["device.engine.pallas_fallbacks"] == 0
-
-
 def _drive_fleet_grid_ell(state: dict) -> None:
     """Fleet product on a grid: no banded structure, so the ELL fallback
     and its fixed-sweep kernels run."""
@@ -752,7 +712,6 @@ DRIVERS: tuple[tuple[str, Callable[[dict], None]], ...] = (
     ("fleet_ring", _drive_fleet_ring),
     ("delta", _drive_delta),
     ("blocked", _drive_blocked),
-    ("pallas", _drive_pallas),
     ("fleet_grid_ell", _drive_fleet_grid_ell),
     ("allsources_legacy", _drive_allsources_legacy),
     ("ksp", _drive_ksp),

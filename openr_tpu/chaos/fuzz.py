@@ -7,7 +7,7 @@ chunks, KvStore TTL storms, replica-fleet kills/partitions, armed
 and crossed over across families, every run is scored by a coverage
 fingerprint built from deterministic counter-state deltas and
 dispatch-rung traversal (delta / fused-warm / blocked / pipelined /
-pallas / rewire / restage), and an oracle bundle is evaluated after
+rewire / restage), and an oracle bundle is evaluated after
 every run.  Timelines that surface new coverage join the corpus;
 timelines that violate an oracle are delta-debugged down to a minimal
 reproducer and checked in under ``tests/chaos_corpus/`` as auto-collected
@@ -84,7 +84,6 @@ ARMABLE_OPS = (
     "rewire",
     "delta_frontier",
     "delta_relax",
-    "pallas",
     "blocked_round",
     "blocked_product",
 )
@@ -113,10 +112,6 @@ _FP_ENGINE_KEYS = (
     "device.engine.delta_dispatches",
     "device.engine.delta_overflow_fallbacks",
     "device.engine.epoch_invalidations",
-    "device.engine.pallas_products",
-    "device.engine.pallas_outer_updates",
-    "device.engine.pallas_fallbacks",
-    "device.engine.pallas_skips",
 )
 _FP_BLOCKED_KEYS = (
     "mesh.blocked.products",
@@ -331,10 +326,6 @@ class _FuzzWorld:
         self.armed: dict[str, int] = {}
         self.fired: list = []
         self.engine.fault_hook = self._fault_hook
-        # pin the Pallas policy regardless of OPENR_PALLAS so two runs of
-        # the same timeline see the same rung in any environment
-        self._saved_pallas = self.engine.pallas_mode
-        self.engine.pallas_mode = "off"
 
         # scripted facts for oracles + fingerprint
         self.rebuilds = 0
@@ -862,14 +853,6 @@ class _FuzzWorld:
         self.scenario.step(f"fuzz:engine:arm:{op}")
         self.tokens.add(f"arm:{op}")
 
-    def _ev_engine_pallas_mode(self, p: dict) -> None:
-        mode = str(p.get("mode", "interpret"))
-        if mode not in ("off", "interpret"):
-            mode = "off"
-        self.engine.pallas_mode = mode
-        self.scenario.step(f"fuzz:engine:pallas_mode:{mode}")
-        self.tokens.add(f"pallas_mode:{mode}")
-
     def _ev_engine_spf(self, p: dict) -> None:
         exact = self._spf_exact(int(p.get("off", 0)))
         if not exact:
@@ -1240,7 +1223,6 @@ class _FuzzWorld:
 
     def close(self) -> None:
         self.engine.fault_hook = None
-        self.engine.pallas_mode = self._saved_pallas
         # release the run's device residency: csr mirrors are per-run
         # objects, keeping them resident would leak across the session
         self.engine.drop(self.csr)
@@ -1361,15 +1343,9 @@ def _rand_event(rng: random.Random, family: str) -> FuzzEvent:
         )
         return FuzzEvent("snapshot", kind, {})
     # engine
-    kind = rng.choice(("arm", "spf", "spf", "pallas_mode", "blocked"))
+    kind = rng.choice(("arm", "spf", "spf", "blocked"))
     if kind == "arm":
         return FuzzEvent("engine", "arm", {"op": rng.choice(ARMABLE_OPS)})
-    if kind == "pallas_mode":
-        return FuzzEvent(
-            "engine",
-            "pallas_mode",
-            {"mode": rng.choice(("interpret", "off"))},
-        )
     if kind == "blocked":
         return FuzzEvent("engine", "blocked", {})
     return FuzzEvent("engine", "spf", {"off": rng.randrange(_N)})
@@ -1480,7 +1456,7 @@ def fuzz(
 
     `budget_s` > 0 bounds wall time: remaining runs are SHED LOUDLY
     (`result.shed`, stderr note) instead of letting a slow box time the
-    whole suite out — the bench.py budget discipline.
+    whole suite out.
 
     `sched_n` > 0 additionally samples that many schedules from the
     OPENR_SCHED explorer (analysis/sched.py) and merges their

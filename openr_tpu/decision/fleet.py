@@ -411,12 +411,6 @@ class FleetRouteView:
             if self._engine is not None
             else asrc.reduced_all_sources
         )
-        # Pallas rung: engine-routed products run the fused epilogue
-        # through the engine's demotion contract (counters + chaos
-        # seam); engine-less calls keep the env-policy default
-        pallas_run = (
-            self._engine.run_pallas if self._engine is not None else None
-        )
         dist, bitmap, ok = product(
             dest_ids,
             runner,
@@ -426,7 +420,6 @@ class FleetRouteView:
             self.csr.node_overloaded,
             init_dist=init,
             maps=maps,
-            pallas_run=pallas_run,
         )
         # `ok` is a host bool by reduced_all_sources' contract (fetched
         # inside, fused with the block-counter read)
@@ -446,7 +439,6 @@ class FleetRouteView:
                 self.csr.edge_up,
                 self.csr.node_overloaded,
                 maps=maps,
-                pallas_run=pallas_run,
             )
         # host bool per the same contract
         assert ok, "fleet reverse SSSP did not reach its fixed point"

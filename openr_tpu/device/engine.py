@@ -71,14 +71,6 @@ ENGINE_COUNTER_KEYS = (
     "device.engine.rewire_bytes_staged",
     "device.engine.rewire_us",
     "device.engine.rewire_fallbacks",
-    # Pallas kernel rung (ops.pallas_kernels): launches that ran the
-    # hand-tiled kernels, demotions to the XLA path, and policy-off
-    # skips.  Pre-seeded like every family so both wire surfaces dump
-    # the keys before the first dispatch.
-    "device.engine.pallas_products",
-    "device.engine.pallas_outer_updates",
-    "device.engine.pallas_fallbacks",
-    "device.engine.pallas_skips",
 )
 
 # affected-column padding ladder for the delta rung: a frontier of
@@ -317,11 +309,6 @@ class DeviceResidencyEngine:
         self._delta_buckets_seen: set = set()
         # chaos seam: called with an op name at every engine entry point
         self.fault_hook: Optional[Callable[[str], None]] = None
-        # Pallas policy override: None resolves the OPENR_PALLAS env
-        # knob (ops.pallas_kernels.pallas_mode); tests and the program
-        # auditor pin "interpret"/"off" here instead of mutating the
-        # environment (the _drive_blocked threshold discipline)
-        self.pallas_mode: Optional[str] = None
         # third dispatch rung (delta < fused full < blocked): node-axis
         # sharded blocked APSP (parallel.blocked).  Eagerly constructed
         # so its pre-seeded mesh.blocked.* counters dump before the
@@ -331,7 +318,7 @@ class DeviceResidencyEngine:
         from ..parallel.blocked import BlockedApspEngine
 
         self.blocked = BlockedApspEngine(parent=self)
-        # per-query attribution (read by bench rows)
+        # per-query attribution: bytes staged and wall time of the last query
         self.last_query_bytes = 0
         self.last_query_us = 0
 
@@ -890,37 +877,6 @@ class DeviceResidencyEngine:
                 "device.engine.dispatch_us",
                 int((time.perf_counter() - t0) * 1e6),
             )
-
-    def run_pallas(self, kind: str, pallas_thunk, xla_thunk):
-        """Engine face of the Pallas dispatch contract
-        (ops.pallas_kernels.run_with_fallback): binds this engine's
-        counter and chaos seams so every launch, skip and (interpret
-        mode only) demotion is accounted under `device.engine.pallas_*`."""
-        from ..ops import pallas_kernels as pk
-
-        tr = _trace.TRACE
-        skips0 = self.counters.get("device.engine.pallas_skips", 0)
-        falls0 = self.counters.get("device.engine.pallas_fallbacks", 0)
-        out = pk.run_with_fallback(
-            kind,
-            pallas_thunk,
-            xla_thunk,
-            counters=self.counters,
-            fault_hook=self.fault_hook,
-            mode=self.pallas_mode,
-        )
-        if tr is not None:
-            if self.counters.get("device.engine.pallas_skips", 0) > skips0:
-                kernel = "xla"
-            elif (
-                self.counters.get("device.engine.pallas_fallbacks", 0)
-                > falls0
-            ):
-                kernel = "fallback"
-            else:
-                kernel = "pallas"
-            tr.annotate("engine.kernel", f"{kind}:{kernel}")
-        return out
 
     # -- delta rung ----------------------------------------------------------
 

@@ -176,8 +176,6 @@ def build_epilogue_maps(bg, out: OutEll) -> EpilogueMaps:
         "small_dist",
         "chord_mode",
         "n_words",
-        "pallas",
-        "pallas_interpret",
     ),
 )
 def _fused_progressive_banded(
@@ -196,8 +194,6 @@ def _fused_progressive_banded(
     small_dist: bool,
     chord_mode: bool,
     n_words: int,
-    pallas: bool = False,
-    pallas_interpret: bool = False,
 ):
     """Relax + verify + ECMP bitmap as ONE compiled program, with the
     bitmap folded into the verification pass: the progressive while-loop
@@ -259,27 +255,7 @@ def _fused_progressive_banded(
     )
 
     # fused verify+bitmap epilogue (authoritative exact check: the
-    # while-loop's own certificate is implied by v == d below).  With
-    # the `pallas` static the epilogue runs as the hand-tiled kernel
-    # (ops.pallas_kernels.fused_epilogue): one VMEM-resident product
-    # tile per instance, every group unrolled against it — bit-exact by
-    # construction (same where-expression, integer min).  Callers reach
-    # it through run_with_fallback, never directly.
-    if pallas:
-        from . import pallas_kernels as pk
-
-        bitmap, converged = pk.fused_epilogue(
-            ops,
-            bg,
-            d,
-            resid_slot,
-            band_slot,
-            n_words,
-            interpret=pallas_interpret,
-        )
-        if small_dist:
-            converged = u16_saturation_verdict(d, converged)
-        return d, bitmap, converged, blocks
+    # while-loop's own certificate is implied by v == d below)
     p_dim = d.shape[1]
     fin = d < ops.inf
     v = d
@@ -442,7 +418,6 @@ def reduced_all_sources(
     maps: Optional[EpilogueMaps] = None,
     check_every: int = 4,
     max_blocks: int = 64,
-    pallas_run=None,
 ):
     """Fleet-wide route-building input in one device round:
     (dist [N*, P] jax — dist[v, p] = dist(v -> p), nh_bitmap
@@ -456,7 +431,7 @@ def reduced_all_sources(
 
     `reverse_runner` is an ops.banded.SpfRunner over the REVERSED edge
     arrays (benchmarks.synthetic.reversed_topology / csr mirror).  With
-    `n_sweeps` the call is non-adaptive (bench timing; caller asserts
+    `n_sweeps` the call is non-adaptive (the caller asserts
     convergence).  Adaptive mode doubles the runner's hint on a False
     verdict — then REFINES the hint back down by bounded binary probes,
     exactly like SpfRunner.forward: a doubling overshoot would otherwise
@@ -474,7 +449,7 @@ def reduced_all_sources(
     is no second pass left to schedule, and the fixed-sweep hint (and
     its overshoot) disappears entirely.  `fused=False` forces the
     legacy two-dispatch path; `fused=True` with `n_sweeps` runs the
-    legacy fixed-sweep fused program (bench timing).
+    legacy fixed-sweep fused program.
 
     `init_dist` ([N*, P], either distance dtype) warm-starts the relax
     from a caller-PROVEN elementwise upper bound — the previous product
@@ -486,17 +461,7 @@ def reduced_all_sources(
 
     `maps` (build_epilogue_maps) feeds the fused epilogue; built here
     on first need when not supplied — callers that rebuild repeatedly
-    should build it once per topology snapshot.
-
-    `pallas_run` routes the fused progressive program through the
-    Pallas demotion contract (ops.pallas_kernels.run_with_fallback
-    signature): the epilogue runs as the hand-tiled kernel when the
-    policy engages, demoting to the identical lax program on any
-    failure.  None means env-policy with no engine accounting (the
-    engine front-end passes `DeviceResidencyEngine.run_pallas`, which
-    adds the `device.engine.pallas_*` counters and the chaos seam).
-    Legacy paths (`fused=False`, explicit `n_sweeps`) never engage
-    Pallas — the kernel exists for the progressive epilogue only."""
+    should build it once per topology snapshot."""
     import numpy as _np
 
     if fused and n_sweeps is not None and init_dist is not None:
@@ -515,9 +480,7 @@ def reduced_all_sources(
             maps = build_epilogue_maps(reverse_runner.bg, out)
         _, _, r_met, r_up, r_ov = reverse_runner.call_arrays()
 
-        def run_prog(
-            small: bool, pallas: bool = False, interp: bool = False
-        ):
+        def run_prog(small: bool):
             return _fused_progressive_banded(
                 dest_ids,
                 reverse_runner.bg,
@@ -534,21 +497,10 @@ def reduced_all_sources(
                 small_dist=small,
                 chord_mode=reverse_runner.chord_mode,
                 n_words=out.n_words,
-                pallas=pallas,
-                pallas_interpret=interp,
             )
 
-        prun = pallas_run
-        if prun is None:
-            from . import pallas_kernels as _pk
-
-            prun = _pk.run_with_fallback
         small = reverse_runner.small_dist
-        dist, bitmap, ok, blocks = prun(
-            "product",
-            lambda interp: run_prog(small, pallas=True, interp=interp),
-            lambda: run_prog(small),
-        )
+        dist, bitmap, ok, blocks = run_prog(small)
         # One explicit fetch for the convergence certificate + block count:
         # the retry/hint decisions below are host control flow, and reading
         # the two scalars piecemeal (bool(ok), bool(ok), int(blocks)) would
@@ -558,11 +510,7 @@ def reduced_all_sources(
             # saturation presents as non-convergence: latch uint16 off
             # (the SpfRunner.adapt discipline) and retry once in int32
             reverse_runner.small_allowed = False
-            dist, bitmap, ok, blocks = prun(
-                "product",
-                lambda interp: run_prog(False, pallas=True, interp=interp),
-                lambda: run_prog(False),
-            )
+            dist, bitmap, ok, blocks = run_prog(False)
             ok_h, blocks_h = jax.device_get((ok, blocks))
         if ok_h and init_dist is None:
             # teach the fixed-sweep hint from the cold progressive run

@@ -279,8 +279,8 @@ def _lookahead(nrow, ncol, row_p, col_p, node_overloaded, k, k_next, *, mesh):
     """Round-(k+1) panel prefetch from the round-k panels.
 
     nrow [S, B, T, B] / ncol [S, T, B, B] are the k+1 panel slices with
-    round k's WRITE-BACK already applied (sliced from the written-back
-    matrix by the fused root, or emulated by `blocked_lookahead`).
+    round k's WRITE-BACK already applied (sliced by
+    `blocked_round_pipelined` from the written-back matrix).
     Three steps, each bit-exact against slicing the bulk-synchronous
     result:
 
@@ -417,44 +417,6 @@ def blocked_round_pipelined(dist, row_p, col_p, node_overloaded, k, *, mesh: Mes
     return lax.with_sharding_constraint(dist, s_dist), nrow_p, ncol_p
 
 
-@functools.partial(jax.jit, static_argnames=("mesh",))
-def blocked_lookahead(dist, row_p, col_p, node_overloaded, k, *, mesh: Mesh):
-    """Read-only round-(k+1) panel prefetch for the split pipelined
-    round (the Pallas phase-3 rung owns the donation, so the prefetch
-    must not consume dist).  Round k's write-back is emulated on the
-    two k+1 slices only: the col-tile-k block of the next row panel is
-    the round-k col panel's tile-(k+1) block, and symmetrically for
-    the next col panel."""
-    k_next = k + 1
-    nrow = lax.dynamic_index_in_dim(dist, k_next, axis=1, keepdims=False)
-    nrow = lax.dynamic_update_index_in_dim(
-        nrow,
-        lax.dynamic_index_in_dim(col_p, k_next, axis=1, keepdims=False),
-        k,
-        axis=2,
-    )
-    ncol = lax.dynamic_index_in_dim(dist, k_next, axis=3, keepdims=False)
-    ncol = lax.dynamic_update_index_in_dim(
-        ncol,
-        lax.dynamic_index_in_dim(row_p, k_next, axis=2, keepdims=False),
-        k,
-        axis=1,
-    )
-    return _lookahead(
-        nrow, ncol, row_p, col_p, node_overloaded, k, k_next, mesh=mesh
-    )
-
-
-def _outer_pallas_thunk(dist, row_p, col_p, ov, k, interpret: bool):
-    """Phase-3 pallas thunk in the run_with_fallback calling shape
-    (trailing `interpret` bound by the demotion policy)."""
-    from ..ops import pallas_kernels as pk
-
-    return pk.blocked_outer_pallas(
-        dist, row_p, col_p, ov, k, interpret=interpret
-    )
-
-
 @functools.partial(jax.jit, static_argnames=("n", "mesh"))
 def blocked_extract(dist, tile_id, lane_id, *, n: int, mesh: Mesh):
     """[N, P] int32 destination columns of the S=0 slice: drev[v, p] =
@@ -517,7 +479,7 @@ class BlockedApspEngine:
         self.fault_hook = None
         # pinned pipeline override ("0" off / "1" on); None consults
         # OPENR_BLOCKED_PIPELINE — the program auditor pins this
-        # attribute instead of env-forcing, like `pallas_mode`
+        # attribute instead of env-forcing
         self.pipeline_mode: str | None = None
 
     # -- counters -----------------------------------------------------------
@@ -680,42 +642,11 @@ class BlockedApspEngine:
             + b * n_pad * (cols - 1) // max(cols, 1)
             + b * b
         )
-        # Pallas phase-3 rung (ops.pallas_kernels.blocked_outer_pallas):
-        # single-device meshes only — the kernel is not shard_map'd, so
-        # launching it on a sharded tile tensor would all-gather the
-        # matrix.  The parent engine owns the policy, the
-        # device.engine.pallas_* accounting and the chaos seam; a
-        # standalone rung (no parent) always takes the XLA phase.  The
-        # launch is decided here, once per closure: off, or tiles the
-        # compiled kernel refuses, is one counted skip and the XLA phase
-        # for every round (the pipelined loop then keeps its fused
-        # blocked_round_pipelined root; the split lookahead+outer rounds
-        # exist only to order the Pallas donation).
-        run_pallas = None
-        parent = self._parent
-        if mesh.devices.size == 1 and parent is not None:
-            from ..ops import pallas_kernels as pk
-
-            eff = parent.pallas_mode
-            eff = eff if eff is not None else pk.pallas_mode()
-            reason = "off" if eff == "off" else None
-            if eff == "compiled":
-                reason = pk.outer_conformance(s, t, b)
-            if reason is None:
-                run_pallas = parent.run_pallas
-            else:
-                pk.count_skip(parent.counters, "outer", reason)
-                if tr is not None:
-                    tr.annotate("engine.kernel", "outer:xla")
-        split_rounds = run_pallas is not None
         if self.pipeline_enabled(t):
             dist = jax.device_put(dist0.reshape(s, t, b, t, b), s_dist)
             try:
                 return (
-                    self._rounds_pipelined(
-                        dist, ov, t, mesh, run_pallas, round_bytes,
-                        split_rounds,
-                    ),
+                    self._rounds_pipelined(dist, ov, t, mesh, round_bytes),
                     b,
                 )
             except Exception:
@@ -724,30 +655,11 @@ class BlockedApspEngine:
                 self._bump("mesh.blocked.pipeline_fallbacks")
         dist = jax.device_put(dist0.reshape(s, t, b, t, b), s_dist)
         return (
-            self._rounds_bulk(dist, ov, t, mesh, run_pallas, round_bytes),
+            self._rounds_bulk(dist, ov, t, mesh, round_bytes),
             b,
         )
 
-    def _outer_step(self, dist, row_p, col_p, ov, kk, mesh, run_pallas):
-        """Round-k phase 3 through the dispatch rung: Pallas with the
-        XLA thunk as the (interpret-mode) demotion target, or plain
-        `blocked_outer`."""
-        if run_pallas is not None:
-            # every demotion trigger raises at/before trace time
-            # (pallas_kernels.blocked_outer_pallas docstring), so
-            # the donated dist is still intact for the XLA thunk
-            return run_pallas(
-                "outer",
-                functools.partial(
-                    _outer_pallas_thunk, dist, row_p, col_p, ov, kk
-                ),
-                functools.partial(
-                    blocked_outer, dist, row_p, col_p, ov, kk, mesh=mesh
-                ),
-            )
-        return blocked_outer(dist, row_p, col_p, ov, kk, mesh=mesh)
-
-    def _rounds_bulk(self, dist, ov, t, mesh, run_pallas, round_bytes):
+    def _rounds_bulk(self, dist, ov, t, mesh, round_bytes):
         """The bulk-synchronous round loop: every round serializes
         diag closure -> panel broadcasts -> outer update."""
         for k in range(t):
@@ -758,9 +670,7 @@ class BlockedApspEngine:
             t1 = time.monotonic_ns()
             row_p, col_p = blocked_panels(dist, closed, ov, kk, mesh=mesh)
             t2 = time.monotonic_ns()
-            dist = self._outer_step(
-                dist, row_p, col_p, ov, kk, mesh, run_pallas
-            )
+            dist = blocked_outer(dist, row_p, col_p, ov, kk, mesh=mesh)
             t3 = time.monotonic_ns()
             self._bump("mesh.blocked.tile_updates")
             self._bump("mesh.blocked.panel_broadcasts", 2)
@@ -771,9 +681,7 @@ class BlockedApspEngine:
         self._bump("mesh.blocked.rounds", t)
         return dist
 
-    def _rounds_pipelined(
-        self, dist, ov, t, mesh, run_pallas, round_bytes, split_rounds=False
-    ):
+    def _rounds_pipelined(self, dist, ov, t, mesh, round_bytes):
         """The software-pipelined round loop (t >= 2): the panels are
         double-buffered — each round consumes panels[k] and produces
         panels[k+1] while the round-k outer update runs, so the panel
@@ -794,19 +702,9 @@ class BlockedApspEngine:
             self._hook("blocked_round")
             kk = jnp.int32(k)
             t2 = time.monotonic_ns()
-            if split_rounds:
-                # split round: the read-only prefetch is enqueued
-                # first, then the Pallas outer consumes (donates) dist
-                nrow_p, ncol_p = blocked_lookahead(
-                    dist, row_p, col_p, ov, kk, mesh=mesh
-                )
-                dist = self._outer_step(
-                    dist, row_p, col_p, ov, kk, mesh, run_pallas
-                )
-            else:
-                dist, nrow_p, ncol_p = blocked_round_pipelined(
-                    dist, row_p, col_p, ov, kk, mesh=mesh
-                )
+            dist, nrow_p, ncol_p = blocked_round_pipelined(
+                dist, row_p, col_p, ov, kk, mesh=mesh
+            )
             t3 = time.monotonic_ns()
             row_p, col_p = nrow_p, ncol_p
             self._bump("mesh.blocked.tile_updates")
@@ -824,7 +722,7 @@ class BlockedApspEngine:
         self._hook("blocked_round")
         kk = jnp.int32(t - 1)
         t2 = time.monotonic_ns()
-        dist = self._outer_step(dist, row_p, col_p, ov, kk, mesh, run_pallas)
+        dist = blocked_outer(dist, row_p, col_p, ov, kk, mesh=mesh)
         t3 = time.monotonic_ns()
         self._bump("mesh.blocked.tile_updates")
         self._bump("mesh.blocked.panel_broadcasts", 2)

@@ -1,8 +1,8 @@
 """JAX's persistent compilation cache, placed from outside.
 
-One helper for every program that compiles for the chip (`main()`,
-`bench.py`): where `JAX_COMPILATION_CACHE_DIR` is set,
-JAX reads it and nothing here sets another directory; otherwise the
+One helper for every program that compiles for the chip (`main()`):
+where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it and nothing
+here sets another directory; otherwise the
 cache lives at a fixed path inside the checkout (`<repo>/.jax_cache`,
 listed in .gitignore).  The path is part of the cache key, so it is
 never temporary, per-process or time-based.  Every executable is

@@ -306,41 +306,148 @@ class TestReducedAllSources:
         bits2 = int(np.asarray(bm2)[0, 0, 0])
         assert {slot_names[b] for b in range(32) if bits2 & (1 << b)} == {1}
 
-    def test_bitmap_matches_reference_ecmp_condition(self):
+    @pytest.mark.parametrize(
+        "case",
+        [
+            "wan128",
+            "ring_odd_n",
+            "grid",
+            "wan_chords",
+            "ring_drained",
+            "grid_drained",
+        ],
+    )
+    def test_bitmap_matches_reference_ecmp_condition(self, case):
         """Bit s set for (v, p) iff out-slot s satisfies
-        metric(v,u) + dist(u,p) == dist(v,p) — decoded against a direct
-        numpy evaluation of the same condition from forward distances."""
-        w = synthetic.wan(128, chords=2, seed=13)
-        asrc, dests, rev, out = self._setup(w, n_prefixes=12)
+        metric(v,u) + dist(u,p) == dist(v,p) with u usable as a transit
+        (not drained, or u is p) — distances and the whole bitmap are
+        checked against a numpy Bellman-Ford and a direct evaluation of
+        the condition.  Cases cover every banded family: wan-shaped with
+        chords, an odd-N ring (padding rows live), a grid, and a drained
+        node on a ring and on a grid, which must never be a transit."""
+        from openr_tpu.decision.csr import _build_out_slots
+        from openr_tpu.ops import allsources as asrc
+
+        topo, dests, drained = _ecmp_case(case)
+        n, e = int(topo.n_nodes), int(topo.n_edges)
+        if isinstance(topo, synthetic.Topology):
+            runner = synthetic.reversed_topology(topo).runner
+            out_slot, _ = _build_out_slots(topo.edge_src, topo.edge_dst, e)
+        else:
+            from openr_tpu.decision.fleet import _reverse_runner
+
+            runner = _reverse_runner(topo)
+            out_slot = topo.out_slot
+        # the fused progressive program exists on banded topologies only
+        assert runner.bg is not None
+        out = asrc.build_out_ell(
+            topo.edge_src, topo.edge_dst, e, n, out_slot=out_slot
+        )
         dist, bitmap, ok = asrc.reduced_all_sources(
-            dests, rev.runner, out, w.edge_metric, w.edge_up,
-            w.node_overloaded,
+            dests, runner, out, topo.edge_metric, topo.edge_up,
+            topo.node_overloaded,
         )
         assert bool(ok)
-        dist = to_i32(dist)  # [N, P] native layout
-        bitmap = np.asarray(bitmap)  # [N, P, W]
-        e = w.n_edges
-        src = w.edge_src[:e]
-        dst = w.edge_dst[:e]
-        met = w.edge_metric[:e]
-        # expected slots per (v, p) from the forward-distance identity
-        from openr_tpu.decision.csr import _build_out_slots
+        dist = to_i32(dist)[:n]  # [N, P] native layout
+        bitmap = np.asarray(bitmap)[:n]  # [N, P, W]
 
-        out_slot, _ = _build_out_slots(w.edge_src, w.edge_dst, e)
-        for p_i in range(len(dests)):
-            d = dist[:, p_i]  # dist(x -> dest p)
-            on = (d[src] < INF32 * 0 + (1 << 30)) & (
-                met + d[dst] == d[src]
+        src = np.asarray(topo.edge_src[:e], np.int64)
+        dst = np.asarray(topo.edge_dst[:e], np.int64)
+        met = np.asarray(topo.edge_metric[:e], np.int64)
+        up = np.asarray(topo.edge_up[:e], bool)
+        ov = np.asarray(topo.node_overloaded[:n], bool)
+        ref = _reference_dist(n, src, dst, met, up, ov, dests)
+        np.testing.assert_array_equal(dist, ref)
+        on = _ecmp_edges(src, dst, met, up, ov, ref)
+        for x in drained:
+            # non-vacuous: without the drain, x is some router's next hop
+            # toward another destination
+            free = np.zeros_like(ov)
+            free_on = _ecmp_edges(
+                src, dst, met, up, free,
+                _reference_dist(n, src, dst, met, up, free, dests),
             )
-            for v in (0, 17, 63, 90):
-                want = {
-                    int(out_slot[ei])
-                    for ei in np.flatnonzero(on & (src == v))
-                }
-                got = set()
-                for wd in range(bitmap.shape[2]):
-                    bits = int(bitmap[v, p_i, wd])
-                    for b in range(32):
-                        if bits & (1 << b):
-                            got.add(32 * wd + b)
-                assert got == want, (v, dests[p_i])
+            assert free_on[dst == x][:, dests != x].any(), x
+        slot = np.asarray(out_slot[:e], np.int64)
+        want = np.zeros_like(bitmap)
+        ei, pi = np.nonzero(on)
+        np.bitwise_or.at(
+            want,
+            (src[ei], pi, slot[ei] // 32),
+            (np.uint32(1) << (slot[ei] % 32)).astype(np.uint32),
+        )
+        np.testing.assert_array_equal(bitmap, want)
+
+        # a drained node is next hop only toward itself
+        got_bit = (bitmap[src, :, slot // 32] >> (slot % 32)[:, None]) & 1
+        for x in drained:
+            into_x = got_bit[dst == x]
+            assert not into_x[:, dests != x].any(), x
+
+
+def _csr_of(dbs, drain=()):
+    from openr_tpu.decision.csr import CsrTopology
+    from openr_tpu.decision.link_state import LinkState
+
+    ls = LinkState()
+    for db in dbs:
+        db.is_overloaded = db.this_node_name in drain
+        ls.update_adjacency_database(db)
+    return CsrTopology.from_link_state(ls)
+
+
+def _ecmp_case(case):
+    """(topology, destination ids, drained node ids) of one case of
+    test_bitmap_matches_reference_ecmp_condition."""
+    from openr_tpu.utils.topo import grid_topology, ring_topology
+
+    if case == "wan128":
+        rng = np.random.default_rng(11)
+        dests = np.sort(rng.choice(128, size=12, replace=False))
+        return synthetic.wan(128, chords=2, seed=13), dests.astype(np.int32), []
+    if case == "wan_chords":
+        topo = synthetic.wan(96, chords=2, seed=3)
+        return topo, np.asarray([0, 5, 17, 48, 95], np.int32), []
+    if case == "ring_odd_n":
+        return _csr_of(ring_topology(65)), np.asarray([0, 7, 31, 64], np.int32), []
+    if case == "grid":
+        return _csr_of(grid_topology(10)), np.arange(0, 100, 9, dtype=np.int32), []
+    if case == "ring_drained":
+        csr = _csr_of(ring_topology(65), drain={"r7"})
+        return csr, np.asarray([0, 7, 40], np.int32), [csr.node_id["r7"]]
+    assert case == "grid_drained"
+    dbs = grid_topology(10)
+    name = dbs[37].this_node_name
+    csr = _csr_of(dbs, drain={name})
+    return csr, np.asarray([0, 37, 99], np.int32), [csr.node_id[name]]
+
+
+def _ecmp_edges(src, dst, met, up, ov, d):
+    """[E, P] bool: edge v->u is an ECMP next hop of v toward p, i.e.
+    metric(v,u) + d(u,p) == d(v,p) < INF and u is not a drained transit
+    (Decision.cpp:1296-1300)."""
+    d_u = d[dst]
+    return (
+        up[:, None]
+        & (d[src] < INF32)
+        & (met[:, None] + d_u == d[src])
+        & (~ov[dst][:, None] | (d_u == 0))
+    )
+
+
+def _reference_dist(n, src, dst, met, up, ov, dests):
+    """[N, P] dist(v -> dests[p]) by Bellman-Ford over the forward
+    edges: a drained node ends paths but relays none (it may still be
+    the origin or the destination)."""
+    d = np.full((n, len(dests)), INF32, np.int64)
+    d[dests, np.arange(len(dests))] = 0
+    src, dst, met = src[up], dst[up], met[up]
+    for _ in range(n):
+        cand = np.minimum(met[:, None] + d[dst], INF32)
+        cand[ov[dst][:, None] & (d[dst] != 0)] = INF32
+        nxt = d.copy()
+        np.minimum.at(nxt, src, cand)
+        if np.array_equal(nxt, d):
+            break
+        d = nxt
+    return d

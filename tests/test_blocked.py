@@ -192,76 +192,97 @@ class TestPhaseUnits:
     """Each phase kernel against a literal numpy transcription of one
     blocked-FW round, drain mask included."""
 
-    def test_three_phases_match_numpy_round(self, eight_cpu_devices):
-        rng = np.random.default_rng(5)
-        t, b, k = 3, 4, 1
+    @pytest.mark.parametrize(
+        "s,t,b,ks,seed,hi,inf_share,drain_share",
+        [
+            (1, 3, 4, (1,), 5, 60, 0.3, 0.2),
+            # wider tiles, two batch slices, every round k, drain mask
+            (2, 3, 16, (0, 1, 2), 0, 1 << 20, 0.1, 0.2),
+            # no drain mask
+            (1, 4, 8, (2,), 3, 1 << 20, 0.1, 0.0),
+        ],
+        ids=["t3_b4", "s2_b16_all_k_drained", "t4_b8_no_mask"],
+    )
+    def test_three_phases_match_numpy_round(
+        self, eight_cpu_devices, s, t, b, ks, seed, hi, inf_share, drain_share
+    ):
+        rng = np.random.default_rng(seed)
         n = t * b
-        d = rng.integers(1, 60, size=(n, n)).astype(np.int64)
-        d[rng.random((n, n)) < 0.3] = INF
-        np.fill_diagonal(d, 0)
-        ov = rng.random(n) < 0.2
+        d_all = rng.integers(1, hi, size=(s, n, n)).astype(np.int64)
+        d_all[rng.random((s, n, n)) < inf_share] = INF
+        for d in d_all:
+            np.fill_diagonal(d, 0)
+        ov = rng.random(n) < drain_share
         mesh = blk.make_blocked_mesh(eight_cpu_devices)
-        dist4 = jnp.asarray(d.astype(np.uint32).reshape(1, t, b, t, b))
+        dist4 = jnp.asarray(d_all.astype(np.uint32).reshape(s, t, b, t, b))
         ovd = jnp.asarray(ov)
-        kk = jnp.int32(k)
-        sl = slice(k * b, (k + 1) * b)
+        for k in ks:
+            kk = jnp.int32(k)
+            closed = blk.blocked_diag(dist4, ovd, kk, mesh=mesh)
+            row_p, col_p = blk.blocked_panels(
+                dist4, closed, ovd, kk, mesh=mesh
+            )
+            dist_new = blk.blocked_outer(
+                jnp.array(dist4), row_p, col_p, ovd, kk, mesh=mesh
+            )
+            got_diag, got_row, got_col, got_d = (
+                np.asarray(jax.device_get(x)).astype(np.int64)
+                for x in (closed, row_p, col_p, dist_new)
+            )
+            for si in range(s):
+                diag, row_ref, col_ref, out = _numpy_round(
+                    d_all[si], ov, k, b
+                )
+                assert np.array_equal(got_diag[si], diag), (si, k)
+                assert np.array_equal(got_row[si].reshape(b, n), row_ref)
+                assert np.array_equal(got_col[si].reshape(n, b), col_ref)
+                assert np.array_equal(got_d[si].reshape(n, n), out), (si, k)
 
-        # phase 1: masked closure of the diagonal tile
-        diag = d[sl, sl].copy()
-        for m in range(b):
-            if ov[k * b + m]:
-                continue
-            diag = np.minimum(
-                diag, np.minimum(diag[:, m : m + 1] + diag[m : m + 1, :], INF)
-            )
-        closed = blk.blocked_diag(dist4, ovd, kk, mesh=mesh)
-        got = np.asarray(jax.device_get(closed)).astype(np.int64)[0]
-        assert np.array_equal(got, diag)
 
-        # phase 2: panel updates through the closed tile (contractions
-        # read the ORIGINAL panels — `closed` is transitively closed, so
-        # one application suffices)
-        row = d[sl, :].copy()
-        col = d[:, sl].copy()
-        row_ref, col_ref = row.copy(), col.copy()
-        for m in range(b):
-            if ov[k * b + m]:
-                continue
-            row_ref = np.minimum(
-                row_ref,
-                np.minimum(diag[:, m : m + 1] + row[m : m + 1, :], INF),
-            )
-            col_ref = np.minimum(
-                col_ref,
-                np.minimum(col[:, m : m + 1] + diag[m : m + 1, :], INF),
-            )
-        row_p, col_p = blk.blocked_panels(dist4, closed, ovd, kk, mesh=mesh)
-        got_row = (
-            np.asarray(jax.device_get(row_p)).astype(np.int64).reshape(b, n)
-        )
-        got_col = (
-            np.asarray(jax.device_get(col_p)).astype(np.int64).reshape(n, b)
-        )
-        assert np.array_equal(got_row, row_ref)
-        assert np.array_equal(got_col, col_ref)
+def _numpy_round(d, ov, k, b):
+    """One blocked-FW round k of the [n, n] matrix d, transcribed
+    literally: (closed diagonal tile, row panel, col panel, matrix after
+    the panel write-back and the masked rank-B outer update)."""
+    sl = slice(k * b, (k + 1) * b)
 
-        # phase 3: panel write-back + masked rank-B outer update
-        ref = d.copy()
-        ref[sl, :] = row_ref
-        ref[:, sl] = col_ref
-        out = ref.copy()
-        for m in range(b):
-            if ov[k * b + m]:
-                continue
-            g = k * b + m
-            out = np.minimum(
-                out, np.minimum(ref[:, g : g + 1] + ref[g : g + 1, :], INF)
-            )
-        dist_new = blk.blocked_outer(dist4, row_p, col_p, ovd, kk, mesh=mesh)
-        got_d = (
-            np.asarray(jax.device_get(dist_new)).astype(np.int64).reshape(n, n)
+    # phase 1: masked closure of the diagonal tile
+    diag = d[sl, sl].copy()
+    for m in range(b):
+        if ov[k * b + m]:
+            continue
+        diag = np.minimum(
+            diag, np.minimum(diag[:, m : m + 1] + diag[m : m + 1, :], INF)
         )
-        assert np.array_equal(got_d, out)
+
+    # phase 2: panel updates through the closed tile (contractions read
+    # the ORIGINAL panels — `closed` is transitively closed, so one
+    # application suffices)
+    row = d[sl, :].copy()
+    col = d[:, sl].copy()
+    row_ref, col_ref = row.copy(), col.copy()
+    for m in range(b):
+        if ov[k * b + m]:
+            continue
+        row_ref = np.minimum(
+            row_ref, np.minimum(diag[:, m : m + 1] + row[m : m + 1, :], INF)
+        )
+        col_ref = np.minimum(
+            col_ref, np.minimum(col[:, m : m + 1] + diag[m : m + 1, :], INF)
+        )
+
+    # phase 3: panel write-back + masked rank-B outer update
+    ref = d.copy()
+    ref[sl, :] = row_ref
+    ref[:, sl] = col_ref
+    out = ref.copy()
+    for m in range(b):
+        if ov[k * b + m]:
+            continue
+        g = k * b + m
+        out = np.minimum(
+            out, np.minimum(ref[:, g : g + 1] + ref[g : g + 1, :], INF)
+        )
+    return diag, row_ref, col_ref, out
 
 
 class TestClosureParity:
@@ -507,6 +528,35 @@ class TestDispatchRung:
         vf = FleetViewCache().view(self._ls(), dests)
         for node in nodes:
             assert np.array_equal(view._row(node), vf._row(node))
+
+    def test_blocked_rung_parity_on_fattree(self):
+        """Fat-trees are never banded: on a single-device mesh the
+        engine-routed blocked rung must serve the same view, distances
+        and ECMP bitmap, as the unblocked fused product."""
+
+        def fat_tree_ls():
+            ls = LinkState()
+            for db in fat_tree_topology(4):
+                ls.update_adjacency_database(db)
+            return ls
+
+        ls = fat_tree_ls()
+        nodes = sorted(ls.node_names)
+        dests = [nodes[0], nodes[3], nodes[-1]]
+        engine = DeviceResidencyEngine()
+        engine.blocked.node_shard_threshold = 0
+        engine.blocked._mesh = blk.make_blocked_mesh(jax.devices("cpu")[:1])
+        vb = FleetViewCache().view(ls, dests, engine=engine)
+        assert vb.converged and vb.node_sharded
+        assert engine.blocked.counters["mesh.blocked.fallbacks"] == 0
+        vf = FleetViewCache().view(fat_tree_ls(), dests)
+        assert vf.converged and not vf.node_sharded
+        for node in nodes:
+            assert np.array_equal(vb._row(node), vf._row(node))
+        assert np.array_equal(
+            np.asarray(jax.device_get(vb._bitmap_dev)),
+            np.asarray(jax.device_get(vf._bitmap_dev)),
+        )
 
 
 def _blocked_product_mode(topo, dest_ids, mesh, tile, pipeline_mode):

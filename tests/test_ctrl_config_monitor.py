@@ -527,59 +527,6 @@ class TestCounterRegistrySweep:
             shim.wait_until_stopped(5)
         assert family <= set(shimmed)
 
-    def test_pallas_family_on_both_wire_surfaces(self, daemon):
-        """The Pallas kernel ledger (launches per kind, demotions,
-        policy skips) is pre-seeded in the engine registry, so the
-        whole device.engine.pallas_* family answers ONE getCounters on
-        the native ctrl server AND the fb303 shim before any kernel
-        ever launches — the runbook's pallas_fallbacks check needs no
-        warm-up query."""
-        import re
-
-        from openr_tpu.device import ENGINE_COUNTER_KEYS
-        from openr_tpu.interop import thrift_binary as tb
-        from openr_tpu.interop.shim import ThriftBinaryShim
-        from test_thrift_binary import _call_ok
-
-        family = {k for k in ENGINE_COUNTER_KEYS if ".pallas_" in k}
-        assert {
-            "device.engine.pallas_products",
-            "device.engine.pallas_outer_updates",
-            "device.engine.pallas_fallbacks",
-            "device.engine.pallas_skips",
-        } <= family
-        name_re = re.compile(r"[a-z][a-z0-9_]*(\.[a-z0-9_]+)+\Z")
-        assert all(name_re.match(k) for k in family)
-
-        client = CtrlClient(port=daemon.ctrl_port)
-        try:
-            native = client.call("getCounters")
-        finally:
-            client.close()
-        assert family <= set(native)
-        assert all(native[k] == 0 for k in family)  # pre-seeded, untouched
-
-        shim = ThriftBinaryShim(
-            daemon.kvstore,
-            port=0,
-            node_name="solo",
-            counters_fn=daemon.ctrl_server.handler._all_counters,
-        )
-        shim.run()
-        try:
-            shimmed = _call_ok(
-                shim.port,
-                "getCounters",
-                43,
-                b"\x00",
-                ("map", tb.T_STRING, tb.T_I64),
-                dec=lambda m: {k.decode(): v for k, v in m.items()},
-            )
-        finally:
-            shim.stop()
-            shim.wait_until_stopped(5)
-        assert family <= set(shimmed)
-
     def test_serving_family_on_both_wire_surfaces(self, daemon):
         """The full serving.* registry (admission, coalescing, shedding,
         latency gauges) answers ONE getCounters on the native ctrl

@@ -343,9 +343,7 @@ class TestOcsRewireAcceptance:
         assert c["device.engine.rewire_rows"] >= 20
         assert c["device.engine.rewire_bytes_staged"] > 0
         # each rewire uploads O(touched slots + rows), bounded by the
-        # one-time graph staging even on this toy topology (the scale
-        # economics — per-rewire bytes vs a wan-sized restage — are the
-        # bench row's claim, see bench.py ocs_rewire_wan100k)
+        # one-time graph staging even on this toy topology
         assert c["device.engine.rewire_bytes_staged"] / 20 < initial_bytes
 
     def test_capacity_overflow_demotes_to_rebuild_restage(self):

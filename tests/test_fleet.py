@@ -319,6 +319,33 @@ class TestFleetBitmapCrossCheck:
             assert view.next_hop_neighbors(me, "4") == route_nhs, me
 
 
+class TestEngineIntegration:
+    def test_fused_product_parity_through_view(self):
+        """The engine-routed fused product (odd-N ring: padding rows
+        live) against the host Dijkstra oracle, every router: distances
+        and the decoded ECMP next-hop neighbors."""
+        from openr_tpu.device.engine import DeviceResidencyEngine
+        from openr_tpu.utils.topo import ring_topology
+
+        ls = LinkState()
+        for db in ring_topology(65):
+            ls.update_adjacency_database(db)
+        dests = ["r0", "r7", "r64"]
+        engine = DeviceResidencyEngine()
+        view = FleetViewCache().view(ls, dests, engine=engine)
+        assert view.converged and not view.node_sharded
+        assert engine.get_counters()["device.engine.dispatches"] >= 1
+        for node in sorted(ls.node_names):
+            spf = ls.run_spf(node)
+            for dest in dests:
+                assert view.dist(node, dest) == spf[dest].metric
+                want = spf[dest].next_hops if dest != node else set()
+                assert view.next_hop_neighbors(node, dest) == want, (
+                    node,
+                    dest,
+                )
+
+
 class TestFleetCache:
     def test_warm_cache_reuses_view(self):
         ls = square()

@@ -1,7 +1,7 @@
 """Coverage-guided chaos fuzzer: tier-1 smoke, determinism, shrinker,
 and the auto-collected chaos_corpus regression replays.
 
-The smoke is BUDGETED the way bench.py is: a wall budget sheds runs
+The smoke is BUDGETED: a wall budget sheds runs
 loudly (`session.shed`) instead of letting a slow box time the whole
 suite out — a shed smoke FAILS with a message naming the knob, never
 hangs.  The `-m slow` soak logs its seed so any failure replays.

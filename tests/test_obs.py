@@ -306,8 +306,7 @@ class TestServingSpanTree:
             assert {"admission", "coalesce", "dispatch", "staged"} <= set(
                 stages
             )
-            # the dispatch stage names the exact engine rung taken and
-            # the kernel flavor that served it
+            # the dispatch stage names the exact engine rung taken
             dispatch = stages["dispatch"]
             assert dispatch["tags"].get("engine.rung") in {
                 "restage",
@@ -317,12 +316,6 @@ class TestServingSpanTree:
                 "rewire",
                 "blocked",
             }, dispatch
-            # kernel attribution only appears on rungs that route through
-            # the pallas/xla fallback wrapper; when present it names the
-            # flavor that actually served the query
-            kernel = dispatch["tags"].get("engine.kernel")
-            if kernel is not None:
-                assert kernel.split(":")[-1] in {"pallas", "fallback", "xla"}
             assert tree["duration_us"] is not None
         finally:
             sched.stop()

@@ -297,8 +297,7 @@ class TestShardingLinearity:
         compiled FLOPs of the sharded SPF step must divide by the
         batch-axis factor (no hidden replication), and the batch-only
         layout's collectives must be only the O(1) convergence-verdict
-        scalar reductions.  Full artifact: benchmarks/mesh_scaling.py
-        (run by bench.py into bench_details.json)."""
+        scalar reductions.  Full artifact: benchmarks/mesh_scaling.py."""
         import jax
         import jax.numpy as jnp
 
