@@ -24,6 +24,9 @@ import numpy as np
 
 from .link_state import Link, LinkState, NodeSpfResult, SpfResult
 
+# counter bumped each time pair_edge_ids() builds its index
+PAIR_INDEX_BUILDS = "decision.whatif_pair_index_builds"
+
 
 def _next_pow2(n: int, floor: int = 8) -> int:
     c = floor
@@ -146,6 +149,8 @@ class CsrTopology:
     _runner: object = None
     # (key, order, start) of in_edges(); a full rebuild resets it to None
     _in_edges: Optional[tuple] = None
+    # (key, pair_keys, ids) of pair_edge_ids(); reset like _in_edges
+    _pair_index: Optional[tuple] = None
 
     @property
     def runner(self):
@@ -668,6 +673,29 @@ class CsrTopology:
             )
             self._in_edges = (key, order, start)
         return self._in_edges[1], self._in_edges[2]
+
+    def pair_edge_ids(self, bump=None) -> tuple[np.ndarray, np.ndarray]:
+        """(pair_keys, ids): the live edge ids sorted by their node pair's
+        key lo * node_capacity + hi (lo, hi = the smaller and the larger
+        endpoint id), parallel links in id order.  The directed edges of
+        every link between a and b are ids[i:j], where i and j are the
+        left and right searchsorted of that pair's key in pair_keys.
+
+        Built once per edge-array state, on in_edges()'s key and with its
+        discipline; each build calls bump(PAIR_INDEX_BUILDS)."""
+        key = (self.rewire_seq, self.n_edges)
+        if self._pair_index is None or self._pair_index[0] != key:
+            ids = np.flatnonzero(self.edge_live[: self.n_edges])
+            src = self.edge_src[ids].astype(np.int64)
+            dst = self.edge_dst[ids].astype(np.int64)
+            pair = np.minimum(src, dst) * self.node_capacity + np.maximum(
+                src, dst
+            )
+            order = np.argsort(pair, kind="stable")
+            self._pair_index = (key, pair[order], ids[order])
+            if bump is not None:
+                bump(PAIR_INDEX_BUILDS)
+        return self._pair_index[1], self._pair_index[2]
 
     def edges_of_links(self) -> dict:
         """Link -> [directed edge ids] (both directions; parallel links map

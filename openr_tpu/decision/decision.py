@@ -607,7 +607,12 @@ class Decision(OpenrEventBase):
             # default the impact view to this router (all-sources at scale
             # is cubic output and would stall the Decision thread)
             srcs = sources if sources is not None else [self.my_node_name]
-            return run(ls, scenarios, srcs, csr=self._protection_csr(ls))
+            csr = self._protection_csr(ls)
+            if csr is not None:
+                # build (and count) the mirror's pair index for this
+                # edge-array state; the resolve in `run` then reads it
+                csr.pair_edge_ids(self.spf_solver._bump)
+            return run(ls, scenarios, srcs, csr=csr)
 
         return self.run_in_event_base_thread(_compute).result()
 

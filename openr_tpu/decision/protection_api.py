@@ -30,19 +30,6 @@ from .link_state import LinkState
 _WHAT_IF_MAX_ELEMENTS = 1 << 28  # 1 GiB of int32
 
 
-def _pair_edge_ids(csr: CsrTopology) -> dict[tuple[str, str], list[int]]:
-    """(sorted node pair) -> directed edge ids of every parallel link
-    between them — one O(E) pass, O(1) per scenario-link lookup."""
-    out: dict[tuple[str, str], list[int]] = {}
-    for e, pair in enumerate(csr.edge_links):
-        if pair is None:  # retired freelist slot
-            continue
-        link = pair[0]
-        key = (link.n1, link.n2) if link.n1 <= link.n2 else (link.n2, link.n1)
-        out.setdefault(key, []).append(e)
-    return out
-
-
 def what_if(
     link_state: LinkState,
     scenarios: list[list[tuple[str, str]]],
@@ -87,17 +74,21 @@ def what_if(
 
     # row 0 = no-failure baseline, rows 1.. = scenarios: one device call
     with _trace.maybe_child("whatif.resolve"):
-        pair_ids = _pair_edge_ids(csr)
+        pair_keys, pair_ids = csr.pair_edge_ids()
         masks = np.ones((len(scenarios) + 1, csr.edge_capacity), dtype=bool)
         resolved: list[dict] = []
         for f, links in enumerate(scenarios):
             known: list[list[str]] = []
             unknown: list[list[str]] = []
             for a, b in links:
-                key = (a, b) if a <= b else (b, a)
-                ids = pair_ids.get(key)
-                if ids:
-                    masks[f + 1, ids] = False
+                i = j = 0
+                u, v = csr.node_id.get(a), csr.node_id.get(b)
+                if u is not None and v is not None:
+                    k = min(u, v) * csr.node_capacity + max(u, v)
+                    i = np.searchsorted(pair_keys, k, side="left")
+                    j = np.searchsorted(pair_keys, k, side="right")
+                if i < j:
+                    masks[f + 1, pair_ids[i:j]] = False
                     known.append([a, b])
                 else:
                     unknown.append([a, b])

@@ -39,6 +39,7 @@ from ..types import (
     UnicastRoute,
     normalize_prefix,
 )
+from .csr import PAIR_INDEX_BUILDS
 from .delta import DELTA_COUNTER_KEYS
 from .fleet import (
     INF32 as FLEET_INF,
@@ -589,10 +590,10 @@ class SpfSolver:
         # best-route selection cache (reference: bestRoutesCache_)
         self.best_routes_cache: dict[str, BestRouteSelectionResult] = {}
         # the decision.delta.* family is pre-seeded so both wire surfaces
-        # expose it from daemon start even before the rung ever engages
-        self.counters: dict[str, int] = {
-            k: 0 for k in DELTA_COUNTER_KEYS + KSP2_COUNTER_KEYS
-        }
+        # expose it from daemon start even before the rung ever engages,
+        # and so is the what-if pair index's (Decision.what_if bumps it)
+        keys = DELTA_COUNTER_KEYS + KSP2_COUNTER_KEYS + (PAIR_INDEX_BUILDS,)
+        self.counters: dict[str, int] = {k: 0 for k in keys}
 
     def _bump(self, counter: str, n: int = 1) -> None:
         self.counters[counter] = self.counters.get(counter, 0) + n

@@ -193,6 +193,7 @@ class EngineBatchBackend:
         ls = self._ls(area)
         self._check_epoch(ls, expect_epoch)
         csr = self.spf.csr_mirror(ls)
+        csr.pair_edge_ids(self._bump)  # counted here, read by the resolve
         return what_if(
             ls,
             [[tuple(link) for link in sc] for sc in scenarios],
